@@ -29,6 +29,9 @@ def _timed(fn, *a, **kw):
 def main() -> None:
     fast = "--fast" in sys.argv
     smoke = "--smoke" in sys.argv
+    from repro.runtime import compile_cache
+
+    compile_cache.enable()
     from benchmarks import kernel_bench
 
     jobs = []

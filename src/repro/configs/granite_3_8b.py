@@ -1,5 +1,5 @@
 """granite-3-8b [dense]: 40L d_model=4096 32H (GQA kv=8) d_ff=12800
-vocab=49155 — GQA [hf:ibm-granite/granite-3.0-2b-base; hf]."""
+vocab=49155 — GQA [hf:ibm-granite/granite-3.0-8b-base config.json]."""
 
 from repro.configs.base import ModelConfig
 from repro.core.sparsity import AWDBB_4_8

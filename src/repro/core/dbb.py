@@ -205,14 +205,22 @@ def pack_bitmask(x: jax.Array, cfg: DBBConfig):
     xb = _to_blocks(x, cfg.bz)
     kept = _to_blocks(topk_block_mask(x, cfg), cfg.bz) & (xb != 0)
     pos = jnp.arange(cfg.bz, dtype=jnp.int32)
-    # set bits first (ascending position), then unset positions
-    key = jnp.where(kept, pos, cfg.bz + pos)
-    order = jnp.argsort(key, axis=-1)[..., : cfg.nnz]
-    vals = jnp.take_along_axis(xb, order, axis=-1)
-    sel = jnp.take_along_axis(kept, order, axis=-1)
-    vals = jnp.where(sel, vals, jnp.zeros_like(vals))
     weights = (2 ** pos).astype(jnp.uint32)
     bitmask = jnp.sum(kept.astype(jnp.uint32) * weights, axis=-1).astype(jnp.uint8)
+    # slot j takes the kept value whose rank (kept positions below it,
+    # a popcount) is j: a select and a block sum per slot, exact because
+    # one term is non-zero — no sort and no gather, whose index tensors
+    # cost more device memory than the weight itself at vocab widths
+    low = bitmask.astype(jnp.int32)[..., None] & ((1 << pos) - 1)
+    rank = jax.lax.population_count(low)
+    zero = jnp.zeros_like(xb)
+    vals = jnp.stack(
+        [
+            jnp.sum(jnp.where(kept & (rank == j), xb, zero), axis=-1)
+            for j in range(cfg.nnz)
+        ],
+        axis=-1,
+    )
     return vals, bitmask
 
 

@@ -112,7 +112,10 @@ def _sample_row(logits, temp, top_k, top_p, seed, pos):
     sp = jnp.sort(probs)[::-1]
     cut = jnp.sum(jnp.cumsum(sp) < top_p)
     thr = sp[jnp.minimum(cut, v - 1)]
-    masked = jnp.where(probs < thr, -jnp.inf, masked)
+    # top_p == 1 keeps every token exactly: the f32 cumulative mass
+    # reaches 1 a little early or late depending on how the program
+    # that samples was compiled, and must not trim the tail by rounding
+    masked = jnp.where((top_p < 1.0) & (probs < thr), -jnp.inf, masked)
     return jax.random.categorical(key, masked).astype(jnp.int32)
 
 

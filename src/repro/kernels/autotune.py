@@ -19,6 +19,7 @@ the winner, so the table improves from measured data rather than folklore.
 from __future__ import annotations
 
 import json
+import math
 import os
 import time
 from typing import Callable, Dict, Iterable, Optional, Tuple
@@ -61,6 +62,11 @@ def _save_cache() -> None:
         pass
 
 
+# Mosaic's block rule: the last two dims of every block are multiples of
+# (8, 128) — sublanes, lanes — or span the whole array axis.
+SUBLANE, LANE = 8, 128
+
+
 def largest_divisor(t: int, n: int, step: int = 1) -> int:
     """Largest multiple of ``step`` that divides ``n`` and is <= ``t``."""
     c = min(t, n)
@@ -70,25 +76,37 @@ def largest_divisor(t: int, n: int, step: int = 1) -> int:
     return max(c, min(step, n))
 
 
+def aligned_divisor(t: int, n: int, align: int) -> int:
+    """Largest multiple of ``align`` that divides ``n`` and is <= ``t``;
+    ``n`` itself when there is none (a block spanning the whole axis is
+    always legal).  ``largest_divisor(1024, 12800, 8)`` is 800, which no
+    lane dim may be; ``aligned_divisor(1024, 12800, 128)`` is 640."""
+    c = min(t, n)
+    c -= c % align
+    while c >= align:
+        if n % c == 0:
+            return c
+        c -= align
+    return n
+
+
 def heuristic_tiles(m: int, k: int, n: int, bz: int, int8: bool = False) -> Tiles:
     """MXU-aligned default tiling for an ``[M,K] x [K,N]`` DBB matmul.
 
-    Targets: TM/TN multiples of 128 where the shape allows (MXU systolic
-    dims), TK a multiple of BZ holding whole blocks, and a combined VMEM
-    working set (x-tile + expanded w-tile + acc) small enough to
-    double-buffer (~<4 MiB at f32).  INT8 wire tiles carry 1-byte values
-    (and the expanded tile is int8, not f32), so the same VMEM budget
-    affords a 2× wider K tile — more accumulation per flush.
+    Every tile is a legal Mosaic block at any shape: TK and TN are lane
+    dims (multiples of 128, or the whole axis), and TM is a lane dim of
+    the A/W kernels' transposed activation tile, so it is a multiple of
+    128 or the whole (decode-sized) M.  TK holds whole BZ blocks, and the
+    combined VMEM working set (x-tile + expanded w-tile + acc) stays
+    small enough to double-buffer (~<4 MiB at f32).  INT8 wire tiles
+    carry 1-byte values, so the same VMEM budget affords a 2× wider K
+    tile — more accumulation per flush.
     """
     # Prefer big N tiles (lane dim) while K is large enough to amortize.
-    tn = largest_divisor(256 if n >= 256 and k <= 2048 else 128, n, 1)
-    if tn < 128:
-        tn = largest_divisor(128, n, 1)
-    tm = largest_divisor(128, m, 1) if m >= 128 else largest_divisor(m, m, 1)
-    tm = max(tm, largest_divisor(8, m, 1))
+    tn = aligned_divisor(256 if n >= 256 and k <= 2048 else LANE, n, LANE)
+    tm = aligned_divisor(LANE, m, LANE)
     # K tile: whole blocks, bounded so x+w tiles fit comfortably in VMEM.
-    tk_cap = 1024 if int8 else 512
-    tk = largest_divisor(tk_cap if k >= tk_cap else k, k, bz)
+    tk = aligned_divisor(1024 if int8 else 512, k, math.lcm(LANE, bz))
     return tm, tk, tn
 
 

@@ -8,13 +8,12 @@ DBB form (``NNZ/BZ`` of the dense bytes + 1-byte bitmask per block-column)
 and are expanded once per (K-tile × N-tile), amortized across the whole
 M-tile — the software analogue of intra-TPE operand reuse.
 
-Rank decode is fully vectorized (one ``cumsum`` for the ranks + one one-hot
-contraction over the NNZ slots): ``dense[b] = bit_b ? values[rank(b)] : 0``
+Rank decode is fully vectorized: ``dense[b] = bit_b ? values[rank(b)] : 0``
 with ``rank(b) = popcount(mask & (2^b - 1))``, computed for every block
-position at once.  No ``O(BZ*NNZ)`` chained-select cascade — the decode
-cost matches the paper's "very low overhead" claim (§6.1).  The one-hot
-contraction is the Mosaic-friendly form of the DP4M8 mux: a data-independent
-select tree rather than a dynamic gather.
+position at once by a SWAR popcount (shifts, ands and adds — Mosaic has no
+``cumsum``), then NNZ data-independent selects over the value slots — the
+Mosaic-friendly form of the DP4M8 mux, not a dynamic gather.  The decode
+cost matches the paper's "very low overhead" claim (§6.1).
 
 Wire format (see ``repro.core.dbb.pack_bitmask``):
     w_vals [K//BZ, NNZ, N]  — j-th set bit's value, ascending positions
@@ -28,10 +27,20 @@ the accumulator through ``repro.kernels.epilogue`` at the final K step, so
 ``y = act(x @ expand(w) + b)`` never materializes the pre-activation tensor.
 
 The kernels are validated in ``interpret=True`` mode against the pure-jnp
-oracles in ``ref.py`` (this container is CPU-only; TPU is the target).
-Mosaic layout note: the expansion assembles the dense tile by collapsing
-``[KB, BZ, TN] -> [KB*BZ, TN]`` — a second-minor reshape with the 128-lane
-dim unchanged, which Mosaic supports for (8,128)-aligned tiles.
+oracles in ``ref.py``, and compiled ahead of time for a described v5e chip
+in ``tests/test_tpu_compile.py``.  Mosaic layout notes:
+
+* every block's last two dims are multiples of (8, 128) or whole axes, so
+  the tiles come from ``autotune.heuristic_tiles`` (TK, TN and the A/W
+  kernels' TM are lane dims: multiples of 128 or the whole axis);
+* the expansion works in 32-bit and assembles the dense tile by collapsing
+  ``[KB, BZ, TN] -> [KB*BZ, TN]`` — whole (8, 128) tiles relabelled, with
+  the lane dim unchanged;
+* the A/W kernels decode the activation in the weight wire's layout
+  (transposed by XLA outside the kernel: ``[KB, NNZ, M]``), giving the
+  ``[TK, TM]`` tile with K on sublanes, and contract it transposed on the
+  MXU — decoding along lanes would need a lane-merging reshape, which
+  Mosaic refuses.
 """
 
 from __future__ import annotations
@@ -47,50 +56,57 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import dbb
 from repro.kernels import autotune, epilogue, ref
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+def _popcount8(v):
+    """Population count of each int32 lane holding an 8-bit value (SWAR:
+    pairs, nibbles, then bytes) — shifts, ands and adds only, which
+    Mosaic lowers (it has no ``cumsum``)."""
+    v = v - ((v >> 1) & 0x55)
+    v = (v & 0x33) + ((v >> 2) & 0x33)
+    return (v + (v >> 4)) & 0x0F
 
 
-def _expand_w_tile(wv, wm, cfg: dbb.DBBConfig):
-    """Expand packed weights [TKB, NNZ, TN] + mask [TKB, TN] -> [TKB*BZ, TN].
+def _expand_tile(vals, mask, cfg: dbb.DBBConfig):
+    """Expand packed values [R, NNZ, C] + mask [R, C] -> dense [R*BZ, C].
 
-    Vectorized rank decode: position ``b`` holds ``values[rank(b)]`` iff bit
-    ``b`` is set, where ``rank(b) = popcount(mask & (2^b - 1))`` — computed
-    for all BZ positions at once as an exclusive cumsum over the unpacked
-    bits, then resolved with a single one-hot contraction over the NNZ
-    slots (exactly one term is non-zero per position, so the sum is exact
-    in any float dtype).
+    Rank decode: position ``b`` holds ``values[rank(b)]`` iff bit ``b`` is
+    set, where ``rank(b) = popcount(mask & (2^b - 1))`` — computed for all
+    BZ positions at once with a SWAR popcount, then resolved by NNZ
+    data-independent selects (the DP4M8 mux; exactly one slot matches per
+    set bit, so the result is exact in any dtype).  The work runs in a
+    32-bit dtype so that collapsing ``[R, BZ, C] -> [R*BZ, C]`` is a pure
+    relabelling of (8, 128) tiles (BZ == 8); the caller casts the dense
+    tile back to the wire dtype, which is exact.
     """
-    tkb, nnz, tn = wv.shape
-    mask = wm.astype(jnp.int32)  # [TKB, TN]
-    bitpos = jax.lax.broadcasted_iota(jnp.int32, (1, cfg.bz, 1), 1)
-    bits = (mask[:, None, :] >> bitpos) & 1  # [TKB, BZ, TN]
-    rank = jnp.cumsum(bits, axis=1) - bits  # popcount of lower bits
-    slot = jax.lax.broadcasted_iota(jnp.int32, (1, 1, nnz, 1), 2)
-    onehot = (rank[:, :, None, :] == slot) & (bits[:, :, None, :] == 1)
-    dense = jnp.sum(
-        wv[:, None, :, :] * onehot.astype(wv.dtype), axis=2
-    )  # [TKB, BZ, TN]
-    return dense.reshape(tkb * cfg.bz, tn)
+    assert cfg.bz <= 8, cfg  # uint8 bitmask
+    r, nnz, c = vals.shape
+    wide = jnp.int32 if jnp.issubdtype(vals.dtype, jnp.integer) else jnp.float32
+    m = mask.astype(jnp.int32)[:, None, :]  # [R, 1, C]
+    pos = jax.lax.broadcasted_iota(jnp.int32, (1, cfg.bz, 1), 1)
+    bits = (m >> pos) & 1  # [R, BZ, C]
+    rank = _popcount8(m & ((1 << pos) - 1))  # popcount of the lower bits
+    v = vals.astype(wide)
+    dense = jnp.zeros((r, cfg.bz, c), wide)
+    for j in range(nnz):  # static: NNZ <= BZ
+        dense = jnp.where(rank == j, v[:, j : j + 1, :], dense)
+    dense = jnp.where(bits == 1, dense, jnp.zeros_like(dense))
+    return dense.reshape(r * cfg.bz, c)
 
 
-def _expand_a_tile(xv, xm, cfg: dbb.DBBConfig):
-    """Expand packed activations [TM, TKB, NNZ] + mask [TM, TKB] -> [TM, TKB*BZ].
+def _act_wire_t(x_vals, x_mask):
+    """Activation wire ``[M, KB, NNZ]`` + ``[M, KB]`` -> the weight wire's
+    layout ``[KB, NNZ, M]`` + ``[KB, M]``, so one rank decode serves both
+    operands and yields the activation tile transposed, ``[TK, TM]``, with
+    K on sublanes (decoding along lanes would need a lane-merging
+    reshape, which Mosaic refuses).  A transpose of the small activation
+    operand, done by XLA outside the kernel."""
+    return jnp.transpose(x_vals, (1, 2, 0)), x_mask.T
 
-    Same vectorized cumsum/one-hot rank decode as :func:`_expand_w_tile`,
-    with the block axis on the minor dim (activation wire layout).
-    """
-    tm, tkb, nnz = xv.shape
-    mask = xm.astype(jnp.int32)  # [TM, TKB]
-    bitpos = jax.lax.broadcasted_iota(jnp.int32, (1, 1, cfg.bz), 2)
-    bits = (mask[:, :, None] >> bitpos) & 1  # [TM, TKB, BZ]
-    rank = jnp.cumsum(bits, axis=2) - bits
-    slot = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, nnz), 3)
-    onehot = (rank[:, :, :, None] == slot) & (bits[:, :, :, None] == 1)
-    dense = jnp.sum(
-        xv[:, :, None, :] * onehot.astype(xv.dtype), axis=3
-    )  # [TM, TKB, BZ]
-    return dense.reshape(tm, tkb * cfg.bz)
+
+def _dot_tn(a_t, b, acc_dtype):
+    """``a_t [K, M]`` transposed times ``b [K, N]`` -> ``[M, N]``."""
+    return jax.lax.dot_general(
+        a_t, b, (((0,), (0,)), ((), ())), preferred_element_type=acc_dtype
+    )
 
 
 def _flush_epilogue(acc_ref, o_ref, b_ref, act):
@@ -108,7 +124,7 @@ def _dbb_matmul_kernel(x_ref, wv_ref, wm_ref, *rest, cfg, nk, act, has_bias):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w_dense = _expand_w_tile(wv_ref[...], wm_ref[...], cfg)  # [TK, TN]
+    w_dense = _expand_tile(wv_ref[...], wm_ref[...], cfg)  # [TK, TN]
     acc_ref[...] += jnp.dot(
         x_ref[...], w_dense.astype(x_ref.dtype), preferred_element_type=jnp.float32
     )
@@ -128,11 +144,10 @@ def _dbb_matmul_aw_kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x_dense = _expand_a_tile(xv_ref[...], xm_ref[...], cfg_a)  # [TM, TK]
-    w_dense = _expand_w_tile(wv_ref[...], wm_ref[...], cfg_w)  # [TK, TN]
-    acc_ref[...] += jnp.dot(
-        x_dense, w_dense.astype(x_dense.dtype), preferred_element_type=jnp.float32
-    )
+    dt = xv_ref.dtype
+    x_t = _expand_tile(xv_ref[...], xm_ref[...], cfg_a).astype(dt)  # [TK, TM]
+    w_dense = _expand_tile(wv_ref[...], wm_ref[...], cfg_w).astype(dt)  # [TK, TN]
+    acc_ref[...] += _dot_tn(x_t, w_dense, jnp.float32)
 
     @pl.when(pl.program_id(2) == nk - 1)
     def _flush():
@@ -196,7 +211,7 @@ def dbb_matmul_pallas(
         out_specs=pl.BlockSpec((tm, tn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -234,9 +249,8 @@ def _dbb_matmul_int8_kernel(x_ref, wv_ref, wm_ref, s_ref, *rest, cfg, nk, act, h
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # rank-decode in int8 (the one-hot sum promotes to int32; exactly one
-    # term per position is non-zero, so the cast back to int8 is exact)
-    w_dense = _expand_w_tile(wv_ref[...], wm_ref[...], cfg).astype(jnp.int8)
+    # rank-decode in int32; the cast back to int8 is exact
+    w_dense = _expand_tile(wv_ref[...], wm_ref[...], cfg).astype(jnp.int8)
     acc_ref[...] += jnp.dot(
         x_ref[...], w_dense, preferred_element_type=jnp.int32
     )
@@ -256,9 +270,9 @@ def _dbb_matmul_aw_int8_kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x_dense = _expand_a_tile(xv_ref[...], xm_ref[...], cfg_a).astype(jnp.int8)
-    w_dense = _expand_w_tile(wv_ref[...], wm_ref[...], cfg_w).astype(jnp.int8)
-    acc_ref[...] += jnp.dot(x_dense, w_dense, preferred_element_type=jnp.int32)
+    x_t = _expand_tile(xv_ref[...], xm_ref[...], cfg_a).astype(jnp.int8)
+    w_dense = _expand_tile(wv_ref[...], wm_ref[...], cfg_w).astype(jnp.int8)
+    acc_ref[...] += _dot_tn(x_t, w_dense, jnp.int32)
 
     @pl.when(pl.program_id(2) == nk - 1)
     def _flush():
@@ -329,7 +343,7 @@ def dbb_matmul_int8_pallas(
         out_specs=pl.BlockSpec((tm, tn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((tm, tn), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -379,13 +393,13 @@ def dbb_matmul_aw_int8_pallas(
         else pl.BlockSpec((1, tn), lambda i, j, kk: (0, j))
     )
     in_specs = [
-        pl.BlockSpec((tm, tkb, nnz_a), lambda i, j, kk: (i, kk, 0)),
-        pl.BlockSpec((tm, tkb), lambda i, j, kk: (i, kk)),
+        pl.BlockSpec((tkb, nnz_a, tm), lambda i, j, kk: (kk, 0, i)),
+        pl.BlockSpec((tkb, tm), lambda i, j, kk: (kk, i)),
         pl.BlockSpec((tkb, nnz_w, tn), lambda i, j, kk: (kk, 0, j)),
         pl.BlockSpec((tkb, tn), lambda i, j, kk: (kk, j)),
         scale_spec,
     ]
-    operands = [x_vals, x_mask, w_vals, w_mask, scale_row]
+    operands = [*_act_wire_t(x_vals, x_mask), w_vals, w_mask, scale_row]
     if bias is not None:
         in_specs.append(pl.BlockSpec((1, tn), lambda i, j, kk: (0, j)))
         operands.append(bias.reshape(1, n))
@@ -403,7 +417,7 @@ def dbb_matmul_aw_int8_pallas(
         out_specs=pl.BlockSpec((tm, tn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((tm, tn), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -444,12 +458,12 @@ def dbb_matmul_aw_pallas(
     nk = k // tk
     grid = (m // tm, n // tn, nk)
     in_specs = [
-        pl.BlockSpec((tm, tkb, nnz_a), lambda i, j, kk: (i, kk, 0)),
-        pl.BlockSpec((tm, tkb), lambda i, j, kk: (i, kk)),
+        pl.BlockSpec((tkb, nnz_a, tm), lambda i, j, kk: (kk, 0, i)),
+        pl.BlockSpec((tkb, tm), lambda i, j, kk: (kk, i)),
         pl.BlockSpec((tkb, nnz_w, tn), lambda i, j, kk: (kk, 0, j)),
         pl.BlockSpec((tkb, tn), lambda i, j, kk: (kk, j)),
     ]
-    operands = [x_vals, x_mask, w_vals, w_mask]
+    operands = [*_act_wire_t(x_vals, x_mask), w_vals, w_mask]
     if bias is not None:
         in_specs.append(pl.BlockSpec((1, tn), lambda i, j, kk: (0, j)))
         operands.append(bias.reshape(1, n))
@@ -467,7 +481,7 @@ def dbb_matmul_aw_pallas(
         out_specs=pl.BlockSpec((tm, tn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -25,6 +25,13 @@ online softmax; see PAPERS.md):
   stream through.  ``S*G`` rows cover a chunked-prefill slice (``S``
   query tokens × ``G`` grouped query heads per KV head), so one kernel
   serves mixed decode+prefill batches exactly like the gather path.
+* The small per-slot planes — slot positions and the int8 wire's
+  per-token scales, ``[N_pages, PS]`` — are gathered per request by XLA
+  (``[B, P, PS]``: bytes the size of the page tables, not of the pages),
+  because a one-page ``(1, PS)`` block of them breaks Mosaic's (8, 128)
+  block rule; each request's ``[P, PS]`` block stays in VMEM and the
+  kernel picks page ``p``'s row.  A page's K/V block is ``[PS, Dk]`` at
+  lane offset ``kv_head * Dk`` (see :func:`supports`).
 * Causal/window masking derives **only** from the gathered slot
   positions (``pos_tbl[tables[b, p]]``), exactly like ``mha``'s
   ``_mask_bias``: ``-1`` slots (empty, null-page, recycled-then-
@@ -36,8 +43,9 @@ online softmax; see PAPERS.md):
   the first valid page, flushing the garbage) — the same invariant the
   gather path documents.
 * Int8-KV caches (``k_scale``/``v_scale`` planes) dequantize **inside
-  the page load**: the int8 tile and its per-token scale column arrive
-  in VMEM and the f32 multiply happens there, mirroring
+  the page load**: the int8 tile and its page's row of per-token scales
+  arrive in VMEM, the row becomes a column there (``_scale_col``) and
+  the f32 multiply happens there, mirroring
   ``quant.dequantize_rows`` elementwise so the kernel sees exactly the
   values the gather path would have materialized.
 * MLA's absorbed decode reuses the same kernel with ``kv_heads=1`` and
@@ -51,8 +59,10 @@ rescaling regroups the softmax sums per page, so float wires match the
 gather path to fp-rounding (~1e-7 rel on f32 — tolerance discussion in
 ``docs/perf.md``) rather than bit-for-bit.  Validated in interpret mode
 against both the gather path and the jnp online-softmax oracle
-(``ref.paged_attn_ref``) in ``tests/test_paged_attn.py``; interpret
-mode doubles as the CPU fallback so the fused wiring runs everywhere.
+(``ref.paged_attn_ref``) in ``tests/test_paged_attn.py``, and compiled
+ahead of time for a described v5e chip in ``tests/test_tpu_compile.py``;
+interpret mode doubles as the CPU fallback so the fused wiring runs
+everywhere.
 Implementation selection (gather vs fused) lives in
 ``kernels/autotune.py`` (kind ``paged_attn``).
 """
@@ -72,16 +82,29 @@ NEG_INF = -1e30  # matches models/attention.py (finite: masked-logit math
 #                  must stay NaN-free through exp/rescale)
 
 
+def _page_row(ref, p):
+    """Row ``p`` of a per-request ``[P, PS]`` table block -> ``[1, PS]``."""
+    return ref[pl.ds(p, 1), :]
+
+
+def _scale_col(row):
+    """``[1, PS]`` per-token scales -> the ``[PS, 1]`` column that scales
+    a page's rows.  A masked lane reduction with one non-zero term per
+    row, so exact (Mosaic transposes no ``(1, PS)`` vector)."""
+    ps = row.shape[-1]
+    r = jax.lax.broadcasted_iota(jnp.int32, (ps, ps), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (ps, ps), 1)
+    diag = jnp.where(r == c, jnp.broadcast_to(row, (ps, ps)), 0.0)
+    return jnp.sum(diag, axis=1, keepdims=True)
+
+
 def _paged_attn_kernel(
-    tbl_ref,  # scalar prefetch: page_tables [B, P] int32
-    q_ref,  # [1, 1, SG, Dk]
-    qpos_ref,  # [1, S] int32
-    pos_ref,  # [1, PS] int32 — this page's slot positions
-    k_ref,  # [1, PS, 1, Dk]
+    tbl_ref,  # scalar prefetch: page_tables [B, P] int32 (walked by the specs)
+    q_ref,  # [SG, Dk] — this (request, kv-head)'s grouped query rows
+    qpos_ref,  # [SG, 1] int32 — each query row's position
+    kpos_ref,  # [P, PS] int32 — the request's gathered slot positions
+    k_ref,  # [PS, Dk] — this page, this kv-head
     *rest,  # [k_scale?], [v?, [v_scale?]], out, acc, m, l
-    s,
-    g,
-    ps,
     dv,
     window,
     scale,
@@ -91,6 +114,7 @@ def _paged_attn_kernel(
     has_vs,
     cdtype,
 ):
+    del tbl_ref
     i = 0
     ks_ref = v_ref = vs_ref = None
     if has_ks:
@@ -115,11 +139,11 @@ def _paged_attn_kernel(
     # page load; int8 pages dequantize HERE (per-token scale column),
     # elementwise-identical to quant.dequantize_rows at the gather
     # boundary, so everything downstream sees the gather path's values
-    k = k_ref[0, :, 0, :]  # [PS, Dk]
+    k = k_ref[...]  # [PS, Dk]
     if ks_ref is not None:
-        k = (k.astype(jnp.float32) * ks_ref[0, :][:, None]).astype(cdtype)
+        k = (k.astype(jnp.float32) * _scale_col(_page_row(ks_ref, p))).astype(cdtype)
 
-    q = q_ref[0, 0]  # [SG, Dk]
+    q = q_ref[...]  # [SG, Dk]
     logits = (
         jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -129,13 +153,12 @@ def _paged_attn_kernel(
 
     # masking from gathered slot positions only (mha._mask_bias semantics:
     # -1 ⇒ empty/null/scrubbed, causal, optional sliding window)
-    kpos = pos_ref[0, :]  # [PS]
-    qp = qpos_ref[0, :]  # [S]
-    valid = (kpos[None, :] >= 0) & (kpos[None, :] <= qp[:, None])
+    kpos = _page_row(kpos_ref, p)  # [1, PS]
+    qp = qpos_ref[...]  # [SG, 1]
+    valid = (kpos >= 0) & (kpos <= qp)
     if window is not None:
-        valid = valid & (kpos[None, :] > qp[:, None] - window)
-    bias = jnp.where(valid, 0.0, NEG_INF).astype(jnp.float32)  # [S, PS]
-    logits = (logits.reshape(s, g, ps) + bias[:, None, :]).reshape(s * g, ps)
+        valid = valid & (kpos > qp - window)
+    logits = logits + jnp.where(valid, 0.0, NEG_INF).astype(jnp.float32)
 
     # flash-style online-softmax update
     m_prev = m_ref[...]
@@ -147,9 +170,11 @@ def _paged_attn_kernel(
     if latent:
         v = k[:, :dv]  # MLA: v IS the latent prefix of the k page
     else:
-        v = v_ref[0, :, 0, :]
+        v = v_ref[...]
         if vs_ref is not None:
-            v = (v.astype(jnp.float32) * vs_ref[0, :][:, None]).astype(cdtype)
+            v = (v.astype(jnp.float32) * _scale_col(_page_row(vs_ref, p))).astype(
+                cdtype
+            )
     pv = jnp.dot(probs.astype(v.dtype), v, preferred_element_type=jnp.float32)
     acc_ref[...] = acc_ref[...] * alpha + pv
     m_ref[...] = m_new
@@ -162,7 +187,7 @@ def _paged_attn_kernel(
         # mean like mha's uniform softmax — garbage either way, and the
         # scheduler never samples them.  The max() is a /0 hedge only.
         out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        o_ref[...] = out.reshape(1, 1, s * g, dv).astype(o_ref.dtype)
+        o_ref[...] = out.astype(o_ref.dtype)
 
 
 def paged_attn_fused(
@@ -189,12 +214,21 @@ def paged_attn_fused(
     and for the latent gather + ``_mla_absorbed`` score/context part
     (``kv_heads=1`` + ``latent_dv``).  ``interpret=True`` runs the same
     kernel body through the Pallas interpreter — the CPU/CI path.
+
+    Blocks (Mosaic's rule: the last two block dims are multiples of
+    (8, 128) or span the array): a page's K/V block is ``[PS, Dk]``
+    taken at lane offset ``kv_head * Dk`` of the ``[PS, KV*Dk]`` page,
+    so it compiles when ``Dk`` is a multiple of 128 or ``kv_heads == 1``
+    (:func:`supports`).  The small per-slot planes (positions, int8
+    scales) are gathered per request by XLA, ``[B, P, PS]``, and one
+    ``[P, PS]`` block per request rides the grid; the kernel picks page
+    ``p``'s row.  Query rows carry their positions as an ``[SG, 1]``
+    column.
     """
     b, s, h, dk = q.shape
     assert h % kv_heads == 0, (h, kv_heads)
     g = h // kv_heads
     sg = s * g
-    n_pages, ps = pos_tbl.shape
     p_cnt = page_tables.shape[1]
     latent = latent_dv is not None
     assert k_pages.shape[-1] == kv_heads * dk, (k_pages.shape, kv_heads, dk)
@@ -206,49 +240,47 @@ def paged_attn_fused(
         dv = v_pages.shape[-1] // kv_heads
     out_dtype = out_dtype or q.dtype
     scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(dk)
+    ps = pos_tbl.shape[1]
+    tables = page_tables.astype(jnp.int32)
 
     # head-major query layout: row r = s*G + g', matching mha's
     # [B, S, KV, G, D] grouping (head h = kv*G + g')
     q_r = q.reshape(b, s, kv_heads, g, dk).transpose(0, 2, 1, 3, 4)
     q_r = q_r.reshape(b, kv_heads, sg, dk)
-    k_r = k_pages.reshape(n_pages, ps, kv_heads, dk)
+    q_col = jnp.repeat(q_pos.astype(jnp.int32), g, axis=1)[..., None]
 
-    # index maps receive the scalar-prefetch ref last: the page-table
-    # walk happens here, per grid step, before the body runs
+    per_req = pl.BlockSpec((None, p_cnt, ps), lambda bb, hh, pp, tbl: (bb, 0, 0))
+
+    def page_spec(d):
+        # index maps receive the scalar-prefetch ref last: the page-table
+        # walk happens here, per grid step, before the body runs
+        return pl.BlockSpec(
+            (None, ps, d), lambda bb, hh, pp, tbl: (tbl[bb, pp], 0, hh)
+        )
+
     in_specs = [
-        pl.BlockSpec((1, 1, sg, dk), lambda bb, hh, pp, tbl: (bb, hh, 0, 0)),
-        pl.BlockSpec((1, s), lambda bb, hh, pp, tbl: (bb, 0)),
-        pl.BlockSpec((1, ps), lambda bb, hh, pp, tbl: (tbl[bb, pp], 0)),
-        pl.BlockSpec(
-            (1, ps, 1, dk), lambda bb, hh, pp, tbl: (tbl[bb, pp], 0, hh, 0)
-        ),
+        pl.BlockSpec((None, None, sg, dk), lambda bb, hh, pp, tbl: (bb, hh, 0, 0)),
+        pl.BlockSpec((None, sg, 1), lambda bb, hh, pp, tbl: (bb, 0, 0)),
+        per_req,
+        page_spec(dk),
     ]
-    operands = [q_r, q_pos.astype(jnp.int32), pos_tbl, k_r]
+    operands = [q_r, q_col, pos_tbl[tables], k_pages]
     if k_scale is not None:
-        in_specs.append(
-            pl.BlockSpec((1, ps), lambda bb, hh, pp, tbl: (tbl[bb, pp], 0))
-        )
-        operands.append(k_scale)
+        in_specs.append(per_req)
+        operands.append(k_scale[tables])
     if not latent:
-        v_r = v_pages.reshape(n_pages, ps, kv_heads, dv)
-        in_specs.append(
-            pl.BlockSpec(
-                (1, ps, 1, dv), lambda bb, hh, pp, tbl: (tbl[bb, pp], 0, hh, 0)
-            )
-        )
-        operands.append(v_r)
+        in_specs.append(page_spec(dv))
+        operands.append(v_pages)
         if v_scale is not None:
-            in_specs.append(
-                pl.BlockSpec((1, ps), lambda bb, hh, pp, tbl: (tbl[bb, pp], 0))
-            )
-            operands.append(v_scale)
+            in_specs.append(per_req)
+            operands.append(v_scale[tables])
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b, kv_heads, p_cnt),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, 1, sg, dv), lambda bb, hh, pp, tbl: (bb, hh, 0, 0)
+            (None, None, sg, dv), lambda bb, hh, pp, tbl: (bb, hh, 0, 0)
         ),
         scratch_shapes=[
             pltpu.VMEM((sg, dv), jnp.float32),  # acc
@@ -259,9 +291,6 @@ def paged_attn_fused(
     out = pl.pallas_call(
         functools.partial(
             _paged_attn_kernel,
-            s=s,
-            g=g,
-            ps=ps,
             dv=dv,
             window=window,
             scale=scale,
@@ -273,10 +302,19 @@ def paged_attn_fused(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv_heads, sg, dv), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        ),
         interpret=interpret,
-    )(page_tables.astype(jnp.int32), *operands)
+    )(tables, *operands)
     out = out.reshape(b, kv_heads, s, g, dv).transpose(0, 2, 1, 3, 4)
     return out.reshape(b, s, h, dv)
+
+
+def supports(kv_heads: int, dk: int) -> bool:
+    """Whether the compiled kernel can take a page's per-head K block:
+    a lane slice of a multiple of 128, or the whole page row."""
+    return kv_heads == 1 or dk % 128 == 0
 
 
 def paged_attn_cache_layer(

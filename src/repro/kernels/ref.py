@@ -5,13 +5,13 @@ mode on CPU, sweeping shapes/dtypes — see tests/test_kernels.py), and they
 double as the shardable ``impl="jnp"`` hot path used for serving on this
 host, so their own speed matters.
 
-The decode is the same vectorized cumsum rank-decode as the kernels
-(``rank(b) = popcount(mask & (2^b - 1))``), but applied **directly in the
-kernel wire layout** — no ``moveaxis``/transpose round-trips through the
-``[..., K]``-major layout of ``dbb.expand_bitmask`` — and the per-position
-value lookup is a single masked ``take_along_axis`` gather (XLA lowers it
-well on CPU/TPU; the Pallas kernels use the equivalent one-hot contraction
-because Mosaic prefers data-independent selects).
+The decode computes the same ranks as the kernels (``rank(b) =
+popcount(mask & (2^b - 1))``, here with XLA's ``population_count``),
+applied **directly in the kernel wire layout** — no round-trips through
+the ``[..., K]``-major layout of ``dbb.expand_bitmask`` — and resolves
+the per-position value lookup with one select per value slot: elementwise
+work XLA fuses, where a ``take_along_axis`` gather lowers on a TPU to an
+indexed gather whose cost model reads hundreds of GB per decode step.
 """
 
 from __future__ import annotations
@@ -25,41 +25,51 @@ from repro.core import dbb, quant
 from repro.kernels import epilogue
 
 
+def _ranks(mask: jax.Array, bz: int):
+    """Per block position ``b`` of each uint8 mask: ``(bit_b, rank(b))``
+    with ``rank(b) = popcount(mask & (2^b - 1))``; the position axis is
+    appended last."""
+    m = mask.astype(jnp.int32)[..., None]
+    pos = jnp.arange(bz, dtype=jnp.int32)
+    return (m >> pos) & 1, jax.lax.population_count(m & ((1 << pos) - 1))
+
+
+def _select_slots(vals, bits, rank):
+    """``dense = bit ? vals[rank] : 0`` as one select per value slot
+    (``vals`` is the list of slot planes, each broadcasting against
+    ``bits``/``rank``) — elementwise, exact in any dtype."""
+    dense = jnp.zeros(bits.shape, vals[0].dtype)
+    for j, v in enumerate(vals):
+        dense = jnp.where((bits == 1) & (rank == j), v, dense)
+    return dense
+
+
 def decode_w(w_vals: jax.Array, w_mask: jax.Array, cfg: dbb.DBBConfig) -> jax.Array:
     """Wire-format weights -> dense ``[K, N]``, decoded in-layout.
 
-    ``w_vals [K//BZ, NNZ, N]``, ``w_mask [K//BZ, N] uint8``.  One cumsum
-    (exclusive, over the unpacked bits) + one masked gather — no NNZ loop,
-    no transposes.
+    ``w_vals [K//BZ, NNZ, N]``, ``w_mask [K//BZ, N] uint8``.  Ranks by
+    popcount, then one select per NNZ slot — no transposes, no gather.
     """
     kb, nnz, n = w_vals.shape
-    mask = w_mask.astype(jnp.int32)  # [KB, N]
-    pos = jnp.arange(cfg.bz, dtype=jnp.int32)
-    bits = (mask[:, None, :] >> pos[None, :, None]) & 1  # [KB, BZ, N]
-    rank = jnp.cumsum(bits, axis=1) - bits  # popcount of lower bits
-    idx = jnp.minimum(rank, nnz - 1)
-    gathered = jnp.take_along_axis(w_vals, idx, axis=1)  # [KB, BZ, N]
-    dense = jnp.where(bits == 1, gathered, jnp.zeros_like(gathered))
+    bits, rank = _ranks(w_mask, cfg.bz)  # [KB, N, BZ]
+    bits, rank = jnp.moveaxis(bits, -1, 1), jnp.moveaxis(rank, -1, 1)
+    dense = _select_slots(
+        [w_vals[:, j : j + 1, :] for j in range(nnz)], bits, rank
+    )  # [KB, BZ, N]
     return dense.reshape(kb * cfg.bz, n)
 
 
 def decode_a(x_vals: jax.Array, x_mask: jax.Array, cfg: dbb.DBBConfig) -> jax.Array:
     """Wire-format activations ``[..., K//BZ, NNZ]`` -> dense ``[..., K]``.
 
-    Same vectorized rank decode with the block axis minor (activation
-    layout); equivalent to ``dbb.expand_bitmask`` but gather-based.
+    Same rank decode with the block axis minor (activation layout);
+    equivalent to ``dbb.expand_bitmask``.
     """
     nnz = x_vals.shape[-1]
-    mask = x_mask.astype(jnp.int32)  # [..., KB]
-    pos = jnp.arange(cfg.bz, dtype=jnp.int32)
-    bits = (mask[..., None] >> pos) & 1  # [..., KB, BZ]
-    rank = jnp.cumsum(bits, axis=-1) - bits
-    idx = jnp.minimum(rank, nnz - 1)
-    # [..., KB, 1, NNZ] gathered at [..., KB, BZ, 1] -> [..., KB, BZ]
-    gathered = jnp.take_along_axis(x_vals[..., None, :], idx[..., None], axis=-1)[
-        ..., 0
-    ]
-    dense = jnp.where(bits == 1, gathered, jnp.zeros_like(gathered))
+    bits, rank = _ranks(x_mask, cfg.bz)  # [..., KB, BZ]
+    dense = _select_slots(
+        [x_vals[..., j : j + 1] for j in range(nnz)], bits, rank
+    )
     return dense.reshape(*dense.shape[:-2], dense.shape[-2] * cfg.bz)
 
 

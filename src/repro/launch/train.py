@@ -4,8 +4,8 @@
         --smoke --steps 50 --ckpt-dir /tmp/ckpt
 
 On a real TPU fleet each host runs this same entry point (jax.distributed
-initializes from the TPU environment); in this container it runs the
-smoke config on CPU.  Demonstrates the full substrate: sharded params,
+initializes from the TPU environment); ``--smoke`` selects the reduced
+config that runs on a CPU.  Demonstrates the full substrate: sharded params,
 W-DBB schedule, DAP training, checkpoint/restart, straggler monitor.
 """
 
@@ -27,7 +27,8 @@ from repro.train.trainer import Trainer, TrainerConfig
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite_3_8b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="reduced same-family config (default: published widths)")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)

@@ -273,17 +273,23 @@ def paged_read(cache_layer, pos_tbl, page_tables, dtype=None):
     return read("k"), read("v"), pos_win
 
 
-def _paged_attn_impl(sp, b: int, sg: int, ps: int, dk: int) -> str:
+def _paged_attn_impl(
+    sp, b: int, sg: int, ps: int, dk: int, kv_heads: int = 1
+) -> str:
     """Resolve the paged-attention implementation for this call site:
     the explicit knob (``SparsityConfig.paged_attn``, threaded from
-    ``ServeConfig.paged_attn``) wins; ``"auto"`` consults
-    ``kernels/autotune`` (benchmark cache → backend heuristic — fused on
-    TPU, gather elsewhere; docs/serving.md has the fallback rules)."""
+    ``ServeConfig.paged_attn``) wins; ``"auto"`` takes the gather path
+    where the compiled kernel has no block for the head width
+    (``paged_attn.supports``) and otherwise consults ``kernels/autotune``
+    (benchmark cache → backend heuristic — fused on TPU, gather
+    elsewhere; docs/serving.md has the fallback rules)."""
     mode = getattr(sp, "paged_attn", "auto") if sp is not None else "auto"
     if mode != "auto":
         return mode
-    from repro.kernels import autotune
+    from repro.kernels import autotune, paged_attn
 
+    if not paged_attn.supports(kv_heads, dk):
+        return "gather"
     return autotune.get_paged_attn_impl(b, sg, ps, dk)
 
 
@@ -416,7 +422,7 @@ def flash_decode(q, cache_layer, new_k, new_v, decode_pos, window_mask, ctx):
         out = out.reshape(bl, 1, h, -1).astype(q_l.dtype)
         return out, k_c, v_c, pos_c
 
-    fn = common.shard_map_compat(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(
@@ -508,7 +514,7 @@ def gqa_forward(
             positions, page_tables,
         )
         ps_sz = cache_layer["k"].shape[1]
-        if _paged_attn_impl(sp, b, s * (h // kvh), ps_sz, dh) == "fused":
+        if _paged_attn_impl(sp, b, s * (h // kvh), ps_sz, dh, kvh) == "fused":
             from repro.kernels import paged_attn as paged_attn_k
 
             out = paged_attn_k.paged_attn_cache_layer(

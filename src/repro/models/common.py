@@ -35,22 +35,6 @@ def dtype_of(name: str):
     return {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[name]
 
 
-def shard_map_compat(fn, *, mesh, in_specs, out_specs, check_vma=False):
-    """Version-compat shard_map: jax.shard_map (new) or
-    jax.experimental.shard_map.shard_map (<=0.4.x, kwarg ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
-
-
 # --------------------------------------------------------------------- init
 
 
@@ -176,9 +160,26 @@ def maybe_pack_input(
 # ------------------------------------------------------------------ forward
 
 
+def _pairwise_sum(x: jax.Array) -> jax.Array:
+    """Sum over the last axis (kept as size 1) by adding halves, zero-
+    padded to a power of two: one association for every shape and every
+    compiled program.  XLA may reorder a ``reduce``, and on a TPU it
+    does so by shape, so the same row summed in the mixed step and in
+    the decode loop came out different in the last bit — enough to move
+    a sampled token and break the serving engine's batch invariance."""
+    n = x.shape[-1]
+    width = 1 << (n - 1).bit_length()
+    if width != n:
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - n)])
+    while x.shape[-1] > 1:
+        half = x.shape[-1] // 2
+        x = x[..., :half] + x[..., half:]
+    return x
+
+
 def rmsnorm(x: jax.Array, p, eps: float = 1e-5) -> jax.Array:
     xf = x.astype(jnp.float32)
-    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+    var = _pairwise_sum(xf * xf) / xf.shape[-1]
     out = xf * jax.lax.rsqrt(var + eps) * p["scale"].astype(jnp.float32)
     return out.astype(x.dtype)
 

@@ -40,24 +40,36 @@ def _stack_specs(specs, n_layers):
     )
 
 
-def init_lm(cfg, key):
-    """Returns (params, specs)."""
-    dtype = dtype_of(cfg.dtype)
-    k_embed, k_layers, k_head = jax.random.split(key, 3)
+def _init_layer(cfg, key, dtype):
+    """One layer's params from its own key (``init_lm`` vmaps this)."""
+    if cfg.family == "ssm":
+        p, _ = ssm_mod.make_mamba2(key, cfg, dtype)
+        n, _ = make_norm(cfg.d_model)
+        return {"mixer": p, "ln": n}
+    return blocks.make_decoder_block(key, cfg, dtype)[0]
+
+
+def _init_outer(cfg, k_embed, k_head, dtype):
+    """Everything outside the layer stack: ``(params, specs)``."""
     params, specs = {}, {}
     params["embed"], specs["embed"] = make_embedding(
         k_embed, cfg.padded_vocab, cfg.d_model, dtype=dtype
     )
+    params["final_norm"], specs["final_norm"] = make_norm(cfg.d_model)
+    if not cfg.tie_embeddings:  # tied heads reuse embed
+        params["lm_head"], specs["lm_head"] = make_linear(
+            k_head, cfg.d_model, cfg.padded_vocab, dtype=dtype, spec=P(DATA, MODEL)
+        )
+    return params, specs
 
-    def one_layer(k):
-        if cfg.family == "ssm":
-            p, _ = ssm_mod.make_mamba2(k, cfg, dtype)
-            n, _ = make_norm(cfg.d_model)
-            return {"mixer": p, "ln": n}
-        return blocks.make_decoder_block(k, cfg, dtype)[0]
+
+def init_lm(cfg, key):
+    """Returns (params, specs)."""
+    dtype = dtype_of(cfg.dtype)
+    k_embed, k_layers, k_head = jax.random.split(key, 3)
+    params, specs = _init_outer(cfg, k_embed, k_head, dtype)
 
     if cfg.family == "ssm":
-        mixer_specs, _ = None, None
         sp_m = ssm_mod.make_mamba2(jax.random.PRNGKey(0), cfg, dtype)[1]
         sp_n = make_norm(cfg.d_model)[1]
         layer_specs = {"mixer": sp_m, "ln": sp_n}
@@ -65,17 +77,53 @@ def init_lm(cfg, key):
         layer_specs = blocks.make_decoder_block(jax.random.PRNGKey(0), cfg, dtype)[1]
 
     keys = jax.random.split(k_layers, cfg.n_layers)
-    params["layers"] = jax.vmap(one_layer)(keys)
+    params["layers"] = jax.vmap(lambda k: _init_layer(cfg, k, dtype))(keys)
     specs["layers"] = _stack_specs(layer_specs, cfg.n_layers)
-
-    params["final_norm"], specs["final_norm"] = make_norm(cfg.d_model)
-    if cfg.tie_embeddings:
-        pass  # reuse embed
-    else:
-        params["lm_head"], specs["lm_head"] = make_linear(
-            k_head, cfg.d_model, cfg.padded_vocab, dtype=dtype, spec=P(DATA, MODEL)
-        )
     return params, specs
+
+
+def init_lm_layerwise(cfg, key, transform):
+    """``init_lm``'s params with ``transform`` applied layer by layer.
+
+    Each layer is made from its own key — the keys ``init_lm`` splits —
+    transformed, and written into a preallocated, donated stack, so only
+    one raw layer exists at a time.  Serving uses this to build packed
+    weights for a model whose raw stack would not fit the device: the
+    result equals ``transform``-ing ``init_lm(cfg, key)[0]`` leaf for
+    leaf whenever ``transform`` acts on each layer alone (as
+    ``serve.engine.pack_params_for_serving`` does).  Layers are made and
+    transformed op by op, as ``init_lm`` is, and not under one ``jit``:
+    XLA's fusion may round differently (a quantization scale, a random
+    normal) by an ulp.  ``transform`` is applied to the params outside
+    the stack too.  Returns params only.
+    """
+    dtype = dtype_of(cfg.dtype)
+    k_embed, k_layers, k_head = jax.random.split(key, 3)
+    keys = jax.random.split(k_layers, cfg.n_layers)
+
+    def layer(k):
+        return transform(_init_layer(cfg, k, dtype))
+
+    # outside the stack first: the vocab-wide head's transients then
+    # have the device to themselves
+    params = transform(_init_outer(cfg, k_embed, k_head, dtype)[0])
+    shapes = jax.eval_shape(layer, keys[0])
+    stack = jax.jit(
+        lambda: jax.tree_util.tree_map(
+            lambda s: jnp.zeros((cfg.n_layers,) + s.shape, s.dtype), shapes
+        )
+    )()
+    put = jax.jit(
+        lambda st, new, i: jax.tree_util.tree_map(
+            lambda a, b: jax.lax.dynamic_update_index_in_dim(a, b, i, 0),
+            st, new,
+        ),
+        donate_argnums=0,
+    )
+    for i in range(cfg.n_layers):
+        stack = put(stack, layer(keys[i]), i)
+    params["layers"] = stack
+    return params
 
 
 def _remat(fn, cfg):

@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.dap import apply_dap
-from repro.models import common
 from repro.models.common import DATA, MODEL, silu
 
 
@@ -220,7 +219,7 @@ def _moe_forward_shard_map(p, x: jax.Array, cfg, ctx, *, layer_idx=None):
         aux = jax.lax.pmean(aux, ea)  # uniform across all axes for out_spec P()
         return y_l.reshape(bl, sl, d).astype(x.dtype), aux
 
-    fn = common.shard_map_compat(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(

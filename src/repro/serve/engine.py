@@ -56,7 +56,7 @@ from repro.core.sampling import (
     sample_tokens,
     validate_sampling,
 )
-from repro.models import common, encdec, lm
+from repro.models import attention, common, encdec, lm
 from repro.runtime import monitor
 from repro.serve import faults, paged_cache
 from repro.serve.scheduler import (
@@ -411,6 +411,36 @@ def pack_params_for_serving(params, cfg, wire_dtype: str = "native"):
     return walk(params)
 
 
+def packed_wire(params) -> Optional[str]:
+    """The wire a params tree already carries: ``"int8"`` or ``"native"``
+    when its linears are packed (``w_vals`` leaves, ``w_scale`` on the
+    int8 wire), ``None`` for raw weights."""
+    if isinstance(params, dict):
+        if "w_vals" in params:
+            return "int8" if "w_scale" in params else "native"
+        for v in params.values():
+            wire = packed_wire(v)
+            if wire is not None:
+                return wire
+    return None
+
+
+def init_serving_params(cfg, key, wire_dtype: str = "native"):
+    """Random packed serving weights for ``cfg``, built layer by layer.
+
+    Equal, leaf for leaf, to ``pack_params_for_serving(lm.init_lm(cfg,
+    key)[0], cfg, wire_dtype)``, but the raw weight stack never exists:
+    each layer is made from its own key, packed, and written into the
+    packed stack (``lm.init_lm_layerwise``).  This is how a model whose
+    raw bf16 weights exceed the device (granite-3-8b: 16.7 GB against a
+    16 GiB v5e) is served on the int8 DBB wire (~5 GB).  Hand the result
+    to :class:`Engine` with the same ``wire_dtype``.
+    """
+    return lm.init_lm_layerwise(
+        cfg, key, lambda p: pack_params_for_serving(p, cfg, wire_dtype)
+    )
+
+
 class Engine:
     """Decoding engine for a batch of prompts: greedy by default
     (``temperature=0``), seeded temperature/top-k/top-p sampling when
@@ -431,11 +461,20 @@ class Engine:
                 f"wdbb/awdbb sparsity mode (got pack_weights="
                 f"{scfg.pack_weights}, mode={cfg.sparsity.mode!r})"
             )
+        # Weights arrive raw (packed here) or already packed for this
+        # wire (init_serving_params — a model whose raw stack does not fit
+        # the device).  The engine keeps no raw copy.
+        given_wire = packed_wire(params)
+        if given_wire is not None and (
+            not packing or given_wire != scfg.wire_dtype
+        ):
+            raise ValueError(
+                f"params are already packed on the {given_wire!r} wire, "
+                f"but this ServeConfig serves pack_weights="
+                f"{scfg.pack_weights}, wire_dtype={scfg.wire_dtype!r}: "
+                "pass raw weights, or weights packed for this wire"
+            )
         raw_params = params  # pre-wire leaves (int8_wire draft packs these)
-        # Snapshots store only serving state, never weights —
-        # Engine.restore() re-packs from the same raw params the caller
-        # holds; keep them so restore paths can hand them around.
-        self._raw_params = raw_params
         if packing:
             params = pack_params_for_serving(params, cfg, scfg.wire_dtype)
         self.params = params
@@ -493,6 +532,12 @@ class Engine:
                 # target already rides the int8 wire: draft == target
                 self._draft_params = self.params
             else:
+                if given_wire is not None:
+                    raise ValueError(
+                        "SpecConfig(draft='int8_wire') packs its draft from "
+                        "raw weights, but these are already packed on the "
+                        f"{given_wire!r} wire: pass raw weights"
+                    )
                 if cfg.sparsity.mode not in ("wdbb", "awdbb"):
                     raise ValueError(
                         "SpecConfig(draft='int8_wire') needs a wdbb/awdbb "
@@ -516,9 +561,6 @@ class Engine:
         # request ids must be unique across calls: the persistent
         # allocator keys page tables by rid
         self._rid = 0
-        # fallback compile counter: distinct dispatch signatures seen
-        # (mirrors jit cache size when ``_cache_size`` is unavailable)
-        self._step_shapes = set()
         # --- robustness state (docs/serving.md "Robustness") ---
         self._injector: Optional[faults.FaultInjector] = None
         self.fallbacks = 0  # fused paged_attn -> gather rebuilds
@@ -669,13 +711,22 @@ class Engine:
         fs = [self._paged_step, self._decode_run]
         if self._spec is not None:
             fs += [self._draft_run, self._verify]
-        n = 0
-        for f in fs:
-            try:
-                n += f._cache_size()
-            except Exception:
-                return len(self._step_shapes)
-        return n
+        return sum(f._cache_size() for f in fs)
+
+    def paged_attn_impl(self) -> str:
+        """The paged-attention implementation the continuous loop's decode
+        step resolves to: ``"fused"`` (``kernels/paged_attn.py``, compiled
+        on a TPU and run by the Pallas interpreter elsewhere) or
+        ``"gather"`` (``paged_read`` + ``mha``)."""
+        cfg, scfg = self.cfg, self.scfg
+        if cfg.mla is not None:
+            sg, dk, kvh = cfg.n_heads, cfg.kv_dim(), 1
+        else:
+            sg, dk = cfg.n_heads // cfg.n_kv_heads, cfg.head_dim()
+            kvh = cfg.n_kv_heads
+        return attention._paged_attn_impl(
+            cfg.sparsity, scfg.max_batch, sg, scfg.page_size, dk, kvh
+        )
 
     def set_faults(self, fcfg: Optional[faults.FaultConfig]) -> None:
         """Arm (or with ``None`` disarm) seeded fault injection for
@@ -1373,7 +1424,6 @@ class Engine:
                 self._step_timer.start()
                 if isinstance(plan, DecodeRun):
                     self.decode_run_calls += 1
-                    self._step_shapes.add(("run",))
                     if self._spec is not None:
                         kept, sampled, bad, cache = self._dispatch_spec(
                             plan, cache, inj
@@ -1414,7 +1464,6 @@ class Engine:
                             bad_at=np.asarray(bad_at),
                         )
                 else:
-                    self._step_shapes.add(("step",) + plan.tokens.shape)
                     args = (
                         self.params, cache,
                         jnp.asarray(plan.tokens), jnp.asarray(plan.positions),
@@ -1613,7 +1662,10 @@ class Engine:
         ``step``-th) published snapshot — re-jit, re-pack weights from
         the RAW ``params``/``cfg`` the caller holds (snapshots store
         serving state, never weights), reload allocator/prefix/KV state,
-        and stage any in-flight requests for :meth:`resume`.
+        and stage any in-flight requests for :meth:`resume`.  Weights
+        already packed for the snapshot's wire (:func:`init_serving_params`)
+        are taken as they are; packed weights for any other wire raise
+        ``ValueError`` (they cannot be re-packed).
 
         ``scfg`` defaults to the snapshot's own serve config; pass an
         override only to change the free knobs (snapshot destination,

@@ -86,25 +86,34 @@ def test_batched_prefill_with_packed_awdbb_weights():
 
 
 def test_int8_wire_serving_token_stable_vs_native():
-    """INT8 wire serving (int8 values + bitmask + scales, int32
-    accumulate, fused dequant) decodes the same greedy tokens as the
-    native-dtype wire on a tiny config — quantization noise must not
-    flip the argmax over a short horizon.  The prompt seed is pinned to
-    one without near-tied logits: on random tiny models the wire's
-    ~0.4%-per-operand noise legitimately flips near-ties (the tolerance
-    discussion in docs/quantization.md), so this is a smoke check of the
-    current per-row-scale path, not a parity proof — the byte-exactness
-    suite below carries the real guarantees."""
+    """INT8 wire serving (int8 weights and per-token int8 activations,
+    int32 accumulate, fused dequant) stays within a bound of the
+    native-dtype wire on the logits of the same tokens.
+
+    Bound: per token, ||logits_int8 - logits_native|| <= 0.1 *
+    ||logits_native||.  Each int8 operand rounds to half a step of
+    amax/127 — about 0.5% RMS relative error per operand on Gaussian
+    data — and the tiny model runs about nine quantized matmuls in
+    sequence (4 per layer + the head), which measures 2-4% here; a
+    wiring fault (a wrong scale or channel) shows up at order 1.  The
+    model is W-DBB (dense activations): under A/W-DBB, DAP's top-4 of 8
+    selection is discontinuous, so 0.5% noise that reorders two
+    near-equal activations swaps whole terms and the wires legitimately
+    differ by tens of percent — no bound a test can pin (argmax tokens
+    from random weights flip for the same reason)."""
     cfg = small_cfg(sparsity=dataclasses.replace(
-        configs.get_config("granite_3_8b", smoke=True).sparsity, mode="awdbb"))
+        configs.get_config("granite_3_8b", smoke=True).sparsity, mode="wdbb"))
     params, _ = lm.init_lm(cfg, jax.random.PRNGKey(4))
-    prompts = _prompts(cfg.vocab, s0=6, seed=9)
+    tokens = jnp.asarray(_prompts(cfg.vocab, s0=6, seed=9))
     kw = dict(max_seq=32, pack_weights=True)
-    out_native = Engine(params, cfg, ServeConfig(**kw)).generate(prompts, 3)
-    out_int8 = Engine(
-        params, cfg, ServeConfig(wire_dtype="int8", **kw)
-    ).generate(prompts, 3)
-    np.testing.assert_array_equal(out_int8, out_native)
+    logits = {}
+    for wire in ("native", "int8"):
+        eng = Engine(params, cfg, ServeConfig(wire_dtype=wire, **kw))
+        out, _ = lm.forward(eng.params, tokens, eng.cfg)
+        logits[wire] = np.asarray(out[..., : cfg.vocab], np.float64)
+    err = np.linalg.norm(logits["int8"] - logits["native"], axis=-1)
+    ref = np.linalg.norm(logits["native"], axis=-1)
+    assert np.all(err <= 0.1 * ref), (err / ref).max()
 
 
 def test_int8_wire_serving_deterministic():
