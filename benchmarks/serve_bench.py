@@ -359,7 +359,7 @@ def bench_serve(smoke: bool = False):
     tps_samp = tok / s_samp
     kv_rows, _ = bench_kv_cache(cfg, params, passes)
     # per-request latency percentiles from the RequestResult timing
-    # fields (staggered arrivals so queue_time is non-trivial); reported
+    # fields (staggered arrivals; each counts from its own); reported
     # for the trajectory, not gated — the µs rows guard these paths
     from repro.runtime import monitor
 

@@ -306,6 +306,7 @@ def paged_attn_fused(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="paged_attn",  # the kernel's name in compiled programs and traces
     )(tables, *operands)
     out = out.reshape(b, kv_heads, s, g, dv).transpose(0, 2, 1, 3, 4)
     return out.reshape(b, s, h, dv)

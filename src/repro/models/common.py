@@ -147,13 +147,15 @@ def maybe_pack_input(
     spec = _active_dap_spec(sparsity, x, layer_idx, first_layer)
     if spec is None:
         return x
-    if all("w_scale" in t for t in targets):  # int8 wire end to end
-        vals, mask, scale = ops.dap_pack_int8(
-            x, spec.nnz, spec.bz,
-            act_scale=sparsity.act_scale if sparsity else "per_tensor",
-        )
-        return PackedAct(vals, mask, spec.cfg, x.shape[-1], x.dtype, scale)
-    vals, mask = ops.dap_pack(x, spec.nnz, spec.bz)
+    # scopes name the packer's ops in compiled programs and device traces
+    with jax.named_scope("dap_pack"):
+        if all("w_scale" in t for t in targets):  # int8 wire end to end
+            vals, mask, scale = ops.dap_pack_int8(
+                x, spec.nnz, spec.bz,
+                act_scale=sparsity.act_scale if sparsity else "per_tensor",
+            )
+            return PackedAct(vals, mask, spec.cfg, x.shape[-1], x.dtype, scale)
+        vals, mask = ops.dap_pack(x, spec.nnz, spec.bz)
     return PackedAct(vals, mask, spec.cfg, x.shape[-1], x.dtype)
 
 
@@ -225,35 +227,8 @@ def linear(
     sp = sparsity
     if isinstance(x, PackedAct):
         if "w_vals" in p:  # joint A/W-DBB: both operands packed
-            cfg_w = dbb.DBBConfig(sp.w_nnz, sp.bz) if sp else dbb.DBBConfig(4, 8)
-            lead = x.vals.shape[:-2]
-            vals2 = x.vals.reshape((-1,) + x.vals.shape[-2:])
-            mask2 = x.mask.reshape((-1,) + x.mask.shape[-1:])
-            if "w_scale" in p:  # int8 wire on both operands
-                if x.scale is not None:
-                    # per-row scales carry one value per token: flatten
-                    # the lead dims alongside the values
-                    x_scale = (
-                        x.scale if x.scale.ndim == 0 else x.scale.reshape(-1)
-                    )
-                else:
-                    # bf16-packed input meets int8 weights (mixed targets):
-                    # quantize the packed values in place, per-tensor
-                    vals2, x_scale = quant.quantize(vals2)
-                y2 = ops.dbb_matmul_aw_int8(
-                    vals2, mask2, x_scale,
-                    p["w_vals"], p["w_mask"], p["w_scale"],
-                    x.cfg, cfg_w,
-                    impl="jnp", bias=p.get("b"), act=act, out_dtype=x.dtype,
-                )
-            else:
-                if x.scale is not None:  # int8-packed input, bf16 weights
-                    vals2 = quant.dequantize(vals2, x.scale, dtype=x.dtype)
-                y2 = ops.dbb_matmul_aw(
-                    vals2, mask2, p["w_vals"], p["w_mask"], x.cfg, cfg_w,
-                    impl="jnp", bias=p.get("b"), act=act, out_dtype=x.dtype,
-                )
-            return y2.reshape(lead + y2.shape[-1:])
+            with jax.named_scope("dbb_linear"):
+                return _linear_aw(p, x, sp, act)
         # Dense weights can't consume the wire format: expand (exact) and
         # continue on the dense path.  DAP is NOT re-applied — packing
         # already pruned.
@@ -268,27 +243,64 @@ def linear(
             x = apply_dap(x, spec)
 
     if "w_vals" in p:  # packed serving weights, dense activations
-        cfg = dbb.DBBConfig(sp.w_nnz, sp.bz) if sp else dbb.DBBConfig(4, 8)
-        lead = x.shape[:-1]
-        x2 = x.reshape(-1, x.shape[-1])
-        if "w_scale" in p:  # int8 wire: dynamic act quant (sp.act_scale)
-            y2 = ops.dbb_matmul_int8(
-                x2, p["w_vals"], p["w_mask"], p["w_scale"], cfg,
-                impl="jnp", bias=p.get("b"), act=act, out_dtype=x.dtype,
-                act_scale=sp.act_scale if sp else "per_tensor",
-            )
-        else:
-            y2 = ops.dbb_matmul(
-                x2, p["w_vals"], p["w_mask"], cfg,
-                impl="jnp", bias=p.get("b"), act=act, out_dtype=x.dtype,
-            )
-        return y2.reshape(*lead, y2.shape[-1])
+        with jax.named_scope("dbb_linear"):
+            return _linear_w(p, x, sp, act)
     y = jnp.einsum("...k,kn->...n", x, p["w"].astype(x.dtype))
     if "b" in p:
         y = y + p["b"].astype(y.dtype)
     # apply_act is dtype-preserving, so the dense path keeps model-dtype
     # numerics (identical to the pre-fusion silu/gelu call sites)
     return epilogue.apply_act(y, act)
+
+
+def _linear_aw(p, x: PackedAct, sp, act) -> jax.Array:
+    """Joint A/W-DBB matmul: a packed activation meets packed weights."""
+    cfg_w = dbb.DBBConfig(sp.w_nnz, sp.bz) if sp else dbb.DBBConfig(4, 8)
+    lead = x.vals.shape[:-2]
+    vals2 = x.vals.reshape((-1,) + x.vals.shape[-2:])
+    mask2 = x.mask.reshape((-1,) + x.mask.shape[-1:])
+    if "w_scale" in p:  # int8 wire on both operands
+        if x.scale is not None:
+            # per-row scales carry one value per token: flatten the lead
+            # dims alongside the values
+            x_scale = x.scale if x.scale.ndim == 0 else x.scale.reshape(-1)
+        else:
+            # bf16-packed input meets int8 weights (mixed targets):
+            # quantize the packed values in place, per-tensor
+            vals2, x_scale = quant.quantize(vals2)
+        y2 = ops.dbb_matmul_aw_int8(
+            vals2, mask2, x_scale,
+            p["w_vals"], p["w_mask"], p["w_scale"],
+            x.cfg, cfg_w,
+            impl="jnp", bias=p.get("b"), act=act, out_dtype=x.dtype,
+        )
+    else:
+        if x.scale is not None:  # int8-packed input, bf16 weights
+            vals2 = quant.dequantize(vals2, x.scale, dtype=x.dtype)
+        y2 = ops.dbb_matmul_aw(
+            vals2, mask2, p["w_vals"], p["w_mask"], x.cfg, cfg_w,
+            impl="jnp", bias=p.get("b"), act=act, out_dtype=x.dtype,
+        )
+    return y2.reshape(lead + y2.shape[-1:])
+
+
+def _linear_w(p, x: jax.Array, sp, act) -> jax.Array:
+    """Packed weights, dense activations."""
+    cfg = dbb.DBBConfig(sp.w_nnz, sp.bz) if sp else dbb.DBBConfig(4, 8)
+    lead = x.shape[:-1]
+    x2 = x.reshape(-1, x.shape[-1])
+    if "w_scale" in p:  # int8 wire: dynamic act quant (sp.act_scale)
+        y2 = ops.dbb_matmul_int8(
+            x2, p["w_vals"], p["w_mask"], p["w_scale"], cfg,
+            impl="jnp", bias=p.get("b"), act=act, out_dtype=x.dtype,
+            act_scale=sp.act_scale if sp else "per_tensor",
+        )
+    else:
+        y2 = ops.dbb_matmul(
+            x2, p["w_vals"], p["w_mask"], cfg,
+            impl="jnp", bias=p.get("b"), act=act, out_dtype=x.dtype,
+        )
+    return y2.reshape(*lead, y2.shape[-1])
 
 
 def pack_linear_params(p, sp: SparsityConfig, wire_dtype: str = "native"):

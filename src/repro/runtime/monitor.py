@@ -1,15 +1,25 @@
 """Runtime health: step-time tracking, straggler detection, preemption
-hooks.  On a real multi-host deployment each host reports its step wall
-time; hosts whose rolling time exceeds the fleet median by
-``threshold``× are flagged (and, with an orchestrator, drained/replaced).
-Here the same logic runs over per-step samples so it is fully unit-tested.
+hooks, and the host spans of the profiler trace.  On a real multi-host
+deployment each host reports its step wall time; hosts whose rolling
+time exceeds the fleet median by ``threshold``× are flagged (and, with
+an orchestrator, drained/replaced).  Here the same logic runs over
+per-step samples so it is fully unit-tested.
+
+Spans (:class:`Span`, :func:`install_gc_spans`) are
+``jax.profiler.TraceAnnotation`` events: while a profiler trace records,
+they land on the host plane of the same trace as the device's programs
+and operations, on its clock; otherwise each costs one check of whether
+a trace is active.
 """
 
 from __future__ import annotations
 
 import collections
+import gc
 import statistics
 import time
+
+from jax.profiler import TraceAnnotation
 
 
 class StepTimer:
@@ -113,3 +123,64 @@ class PreemptionGuard:
     @property
     def should_stop(self) -> bool:
         return self._stop
+
+
+def tracing() -> bool:
+    """True while a profiler trace records.  Guard span arguments that
+    cost work to build with it."""
+    return TraceAnnotation.is_enabled()
+
+
+class Span:
+    """A named host span: a profiler ``TraceAnnotation`` that also keeps
+    its own wall time (``seconds``, ``time.perf_counter``), so a caller
+    times a region and traces it with one pair of stamps.
+
+    ``args`` become the span's arguments in the trace; :meth:`annotate`
+    adds more once they are known (a no-op while no trace records)."""
+
+    __slots__ = ("_ann", "t0", "seconds")
+
+    def __init__(self, name: str, **args):
+        self._ann = TraceAnnotation(name, **args)
+        self.t0 = 0.0
+        self.seconds = 0.0
+
+    def __enter__(self) -> "Span":
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.seconds = time.perf_counter() - self.t0
+        self._ann.__exit__(*exc)
+        return False
+
+    def annotate(self, **args) -> None:
+        self._ann.set_metadata(**args)
+
+
+class _GcSpans:
+    """``gc.callbacks`` hook: one ``python.gc`` span per collection,
+    argument ``generation``, recorded only while a trace records."""
+
+    def __init__(self):
+        self._open = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            if TraceAnnotation.is_enabled():
+                self._open = TraceAnnotation(
+                    "python.gc", generation=info["generation"]
+                )
+                self._open.__enter__()
+        elif self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+
+
+def install_gc_spans() -> None:
+    """Trace every garbage collection of this process as a ``python.gc``
+    span (idempotent)."""
+    if not any(isinstance(cb, _GcSpans) for cb in gc.callbacks):
+        gc.callbacks.append(_GcSpans())

@@ -552,6 +552,7 @@ class Engine:
         self.prefill_calls = 0
         self.decode_calls = 0
         self.step_calls = 0  # continuous dispatches (mixed steps + runs)
+        self.fed_positions = 0  # live positions fed to mixed steps
         self.decode_run_calls = 0  # fused decode runs among step_calls
         self.fused_tokens = 0  # tokens emitted inside fused runs
         # continuous-mode state that persists across generate_requests
@@ -566,7 +567,6 @@ class Engine:
         self.fallbacks = 0  # fused paged_attn -> gather rebuilds
         self._health: Dict[str, float] = {}  # scheduler stats, accumulated
         # --- durability / monitoring (docs/serving.md "Durability") ---
-        self._step_timer = monitor.StepTimer(window=32)
         self._watchdog = monitor.HangWatchdog(threshold=scfg.hang_threshold)
         self._step_samples: collections.deque = collections.deque(maxlen=2048)
         self.slow_steps = 0  # watchdog trips (health()["slow_steps"])
@@ -576,6 +576,7 @@ class Engine:
         # in-flight scheduler state loaded by load_snapshot(), consumed
         # by resume(); while pending, _serve() refuses new work
         self._resume_state: Optional[dict] = None
+        monitor.install_gc_spans()  # python.gc spans while a trace records
 
     def _build_jitted(self) -> None:
         """(Re)build every jitted entry point against ``self.cfg``.
@@ -599,20 +600,23 @@ class Engine:
         )
         # continuous mode: one mixed paged step + per-row sampling at each
         # row's own last valid chunk index, plus the fused decode loop
-        # (dynamic trip count n -> a single compile for every run length)
-        self._paged_step = jax.jit(
-            lambda p, c, t, pos, tbl, scrub, cow: lm.paged_step(
+        # (dynamic trip count n -> a single compile for every run length).
+        # Named functions, so the compiled programs read jit_paged_step,
+        # jit_decode_run, ... in a profiler trace
+        def paged_step(p, c, t, pos, tbl, scrub, cow):
+            return lm.paged_step(
                 p, c, t, pos, tbl, cfg, scrub_pages=scrub, cow_pages=cow
             )
-        )
-        self._decode_run = jax.jit(
-            lambda p, c, t, pos, tbl, scrub, cow, st, sk, sp_, ss, n:
-            lm.paged_decode_loop(
+
+        def decode_run(p, c, t, pos, tbl, scrub, cow, st, sk, sp_, ss, n):
+            return lm.paged_decode_loop(
                 p, c, t, pos, tbl, n, cfg, max_steps=scfg.decode_block,
                 scrub_pages=scrub, cow_pages=cow,
                 sampling=(st, sk, sp_, ss),
             )
-        )
+
+        self._paged_step = jax.jit(paged_step)
+        self._decode_run = jax.jit(decode_run)
         # speculative decoding (SpecConfig): the greedy draft loop on the
         # cheap ladder rung, and the single-pass multi-token verify step
         # under the TARGET config.  Both run over the target's paged
@@ -632,20 +636,21 @@ class Engine:
                 sp_draft = dataclasses.replace(sp_draft, act_scale="per_row")
             dcfg = dataclasses.replace(cfg, sparsity=sp_draft)
             self.draft_cfg = dcfg
-            self._draft_run = jax.jit(
-                lambda p, c, t, pos, tbl, scrub, cow, n:
-                lm.paged_decode_loop(
+
+            def draft_run(p, c, t, pos, tbl, scrub, cow, n):
+                return lm.paged_decode_loop(
                     p, c, t, pos, tbl, n, dcfg,
                     max_steps=scfg.decode_block,
                     scrub_pages=scrub, cow_pages=cow,
                 )
-            )
-            self._verify = jax.jit(
-                lambda p, c, t, pos, tbl, st, sk, sp_, ss:
-                lm.paged_verify(
+
+            def verify(p, c, t, pos, tbl, st, sk, sp_, ss):
+                return lm.paged_verify(
                     p, c, t, pos, tbl, cfg, sampling=(st, sk, sp_, ss)
                 )
-            )
+
+            self._draft_run = jax.jit(draft_run)
+            self._verify = jax.jit(verify)
 
         # sampling fused with the non-finite-logit watchdog: one dispatch
         # returns (token, row-is-clean) per row, so quarantine detection
@@ -1172,7 +1177,9 @@ class Engine:
 
         ``on_token`` streams committed output (see
         :meth:`generate_requests`); results carry queue/TTFT/throughput
-        latency fields (see :class:`RequestResult`)."""
+        latency fields (see :class:`RequestResult`).  The serving runs in
+        a ``serve.requests`` span (argument ``n``; docs/serving.md
+        "Tracing")."""
         scfg = self.scfg
         n = len(prompts)
         n_list = self._per_request("n_tokens", n_tokens, n, None)
@@ -1214,7 +1221,8 @@ class Engine:
                 )
             )
             results.append(None)
-        self._serve([r for r in slots if r is not None])
+        with monitor.Span("serve.requests", n=n):
+            self._serve([r for r in slots if r is not None])
         for i, req in enumerate(slots):
             if req is None:
                 continue
@@ -1256,8 +1264,9 @@ class Engine:
             self._fallback_to_gather(err)
             with faults.scoped(inj):
                 draft_toks, draft_bad, cache = self._draft_run(*draft_args)
-        draft_toks = np.asarray(draft_toks)
-        draft_bad = np.asarray(draft_bad)
+        with monitor.Span("serve.wait"):
+            draft_toks = np.asarray(draft_toks)
+            draft_bad = np.asarray(draft_bad)
         if inj is not None and n_draft:
             mask = inj.draft_poison_mask(plan.rows)
             if mask is not None:
@@ -1292,8 +1301,9 @@ class Engine:
             self._fallback_to_gather(err)
             with faults.scoped(inj):
                 sampled, ok, cache = self._verify(*ver_args)
-        sampled = np.asarray(sampled)
-        ok = np.asarray(ok)
+        with monitor.Span("serve.wait"):
+            sampled = np.asarray(sampled)
+            ok = np.asarray(ok)
         # --- acceptance + watchdogs (host side, pure comparisons)
         kept = np.zeros((b,), np.int32)
         bad = np.zeros((b,), bool)
@@ -1392,108 +1402,49 @@ class Engine:
         point; the ``pre_commit`` kill point sits between each jitted
         dispatch and its scheduler commit (device KV advanced, host
         bookkeeping not — the torn state snapshots must never see).
-        Each compute step is timed for the hang watchdog and the
-        ``health()`` percentiles."""
+        Each iteration runs in a ``serve.iteration`` span (arguments
+        ``it``, ``kind``, ``rows``, ``fed``) whose children name what the
+        host does: ``serve.snapshot``, ``serve.plan``, ``serve.dispatch``,
+        ``serve.sample``, ``serve.wait`` (the blocking read of the step's
+        outputs) and ``serve.commit`` (docs/serving.md "Tracing").  The
+        span's wall time feeds the hang watchdog and the ``health()``
+        percentiles."""
         scfg = self.scfg
         cont = self._ensure_cont()
         inj = self._injector
         cache = cont["cache"]
+        span = monitor.Span
         # every serve/resume loop snapshots its first boundary, then
         # every snapshot_every iterations of this scheduler
         self._last_snap_iter = None
         try:
             while sched.has_work():
-                if scfg.snapshot_every and (
-                    self._last_snap_iter is None
-                    or sched.iteration - self._last_snap_iter
-                    >= scfg.snapshot_every
-                ):
-                    cont["cache"] = cache
-                    self._snapshot_now(sched)
-                    self._last_snap_iter = sched.iteration
-                if inj is not None:
-                    inj.maybe_kill("iteration")
-                    page = inj.scribble_page(cont["allocator"].free_pages())
-                    if page is not None:
-                        cache = self._scribble(cache, jnp.int32(page))
-                plan = sched.plan()
-                if plan is None:  # only future arrivals left: advance time
-                    sched.tick()
-                    continue
-                self.step_calls += 1
-                self._step_timer.start()
-                if isinstance(plan, DecodeRun):
-                    self.decode_run_calls += 1
-                    if self._spec is not None:
-                        kept, sampled, bad, cache = self._dispatch_spec(
-                            plan, cache, inj
-                        )
-                        if inj is not None:
-                            inj.maybe_kill("pre_commit")
-                        sched.commit_spec(plan, kept, sampled, bad_rows=bad)
-                    else:
-                        self.fused_tokens += plan.n_steps
-                        args = (
-                            self.params, cache,
-                            jnp.asarray(plan.tokens),
-                            jnp.asarray(plan.positions),
-                            jnp.asarray(plan.page_tables),
-                            jnp.asarray(plan.scrub_pages),
-                            jnp.asarray(plan.cow_pages),
-                            jnp.asarray(plan.samp_temp),
-                            jnp.asarray(plan.samp_top_k),
-                            jnp.asarray(plan.samp_top_p),
-                            jnp.asarray(plan.samp_seed),
-                            jnp.int32(plan.n_steps),
-                        )
-                        try:
-                            with faults.scoped(inj):
-                                sampled, bad_at, cache = self._decode_run(
-                                    *args
-                                )
-                        except faults.FusedKernelFault as err:
-                            self._fallback_to_gather(err)
-                            with faults.scoped(inj):
-                                sampled, bad_at, cache = self._decode_run(
-                                    *args
-                                )
-                        if inj is not None:
-                            inj.maybe_kill("pre_commit")
-                        sched.commit_run(
-                            plan, np.asarray(sampled),
-                            bad_at=np.asarray(bad_at),
-                        )
-                else:
-                    args = (
-                        self.params, cache,
-                        jnp.asarray(plan.tokens), jnp.asarray(plan.positions),
-                        jnp.asarray(plan.page_tables),
-                        jnp.asarray(plan.scrub_pages),
-                        jnp.asarray(plan.cow_pages),
-                    )
-                    try:
-                        with faults.scoped(inj):
-                            logits, cache = self._paged_step(*args)
-                    except faults.FusedKernelFault as err:
-                        self._fallback_to_gather(err)
-                        with faults.scoped(inj):
-                            logits, cache = self._paged_step(*args)
+                with span("serve.iteration", it=sched.iteration) as it_span:
+                    if scfg.snapshot_every and (
+                        self._last_snap_iter is None
+                        or sched.iteration - self._last_snap_iter
+                        >= scfg.snapshot_every
+                    ):
+                        cont["cache"] = cache
+                        with span("serve.snapshot"):
+                            self._snapshot_now(sched)
+                        self._last_snap_iter = sched.iteration
                     if inj is not None:
-                        mask = inj.poison_mask(plan.rows, plan.sample_mask)
-                        if mask is not None:
-                            logits = self._poison(logits, jnp.asarray(mask))
-                    sampled, ok = self._sample_at(
-                        logits, jnp.asarray(plan.sample_idx),
-                        jnp.asarray(plan.positions),
-                        jnp.asarray(plan.samp_temp),
-                        jnp.asarray(plan.samp_top_k),
-                        jnp.asarray(plan.samp_top_p),
-                        jnp.asarray(plan.samp_seed),
-                    )
-                    if inj is not None:
-                        inj.maybe_kill("pre_commit")
-                    sched.commit(plan, np.asarray(sampled), ok=np.asarray(ok))
-                self._note_step_time(self._step_timer.stop())
+                        inj.maybe_kill("iteration")
+                        page = inj.scribble_page(
+                            cont["allocator"].free_pages()
+                        )
+                        if page is not None:
+                            cache = self._scribble(cache, jnp.int32(page))
+                    with span("serve.plan"):
+                        plan = sched.plan()
+                    if plan is None:  # only future arrivals: advance time
+                        it_span.annotate(kind="tick")
+                        sched.tick()
+                        continue
+                    self.step_calls += 1
+                    cache = self._dispatch(sched, plan, cache, inj, it_span)
+                self._note_step_time(it_span.seconds)
         finally:
             # a SimulatedCrash abandons the loop mid-flight; the engine
             # object is then dead by contract, so publishing the partial
@@ -1501,6 +1452,98 @@ class Engine:
             # path identical to before)
             cont["cache"] = cache
             self._merge_health(sched.stats())
+
+    def _dispatch(self, sched, plan, cache, inj, it_span):
+        """Run one plan on the device and commit it: the body of a
+        ``serve.iteration``.  Returns the updated cache."""
+        span = monitor.Span
+        tracing = monitor.tracing()
+        rows = sum(r is not None for r in plan.rows) if tracing else 0
+        if isinstance(plan, DecodeRun):
+            self.decode_run_calls += 1
+            if tracing:
+                it_span.annotate(
+                    kind="decode_run" if self._spec is None else "spec",
+                    rows=rows, fed=rows * plan.n_steps,
+                )
+            if self._spec is not None:
+                with span("serve.dispatch"):
+                    kept, sampled, bad, cache = self._dispatch_spec(
+                        plan, cache, inj
+                    )
+                if inj is not None:
+                    inj.maybe_kill("pre_commit")
+                with span("serve.commit"):
+                    sched.commit_spec(plan, kept, sampled, bad_rows=bad)
+                return cache
+            self.fused_tokens += plan.n_steps
+            with span("serve.dispatch"):
+                args = (
+                    self.params, cache,
+                    jnp.asarray(plan.tokens),
+                    jnp.asarray(plan.positions),
+                    jnp.asarray(plan.page_tables),
+                    jnp.asarray(plan.scrub_pages),
+                    jnp.asarray(plan.cow_pages),
+                    jnp.asarray(plan.samp_temp),
+                    jnp.asarray(plan.samp_top_k),
+                    jnp.asarray(plan.samp_top_p),
+                    jnp.asarray(plan.samp_seed),
+                    jnp.int32(plan.n_steps),
+                )
+                try:
+                    with faults.scoped(inj):
+                        sampled, bad_at, cache = self._decode_run(*args)
+                except faults.FusedKernelFault as err:
+                    self._fallback_to_gather(err)
+                    with faults.scoped(inj):
+                        sampled, bad_at, cache = self._decode_run(*args)
+            with span("serve.wait"):
+                sampled, bad_at = np.asarray(sampled), np.asarray(bad_at)
+            if inj is not None:
+                inj.maybe_kill("pre_commit")
+            with span("serve.commit"):
+                sched.commit_run(plan, sampled, bad_at=bad_at)
+            return cache
+        fed = sum(plan.n_new)
+        self.fed_positions += fed
+        if tracing:
+            it_span.annotate(kind="mixed", rows=rows, fed=fed)
+        with span("serve.dispatch"):
+            args = (
+                self.params, cache,
+                jnp.asarray(plan.tokens), jnp.asarray(plan.positions),
+                jnp.asarray(plan.page_tables),
+                jnp.asarray(plan.scrub_pages),
+                jnp.asarray(plan.cow_pages),
+            )
+            try:
+                with faults.scoped(inj):
+                    logits, cache = self._paged_step(*args)
+            except faults.FusedKernelFault as err:
+                self._fallback_to_gather(err)
+                with faults.scoped(inj):
+                    logits, cache = self._paged_step(*args)
+            if inj is not None:
+                mask = inj.poison_mask(plan.rows, plan.sample_mask)
+                if mask is not None:
+                    logits = self._poison(logits, jnp.asarray(mask))
+        with span("serve.sample"):
+            sampled, ok = self._sample_at(
+                logits, jnp.asarray(plan.sample_idx),
+                jnp.asarray(plan.positions),
+                jnp.asarray(plan.samp_temp),
+                jnp.asarray(plan.samp_top_k),
+                jnp.asarray(plan.samp_top_p),
+                jnp.asarray(plan.samp_seed),
+            )
+        with span("serve.wait"):
+            sampled, ok = np.asarray(sampled), np.asarray(ok)
+        if inj is not None:
+            inj.maybe_kill("pre_commit")
+        with span("serve.commit"):
+            sched.commit(plan, sampled, ok=ok)
+        return cache
 
     # ----------------------------------------------------------- durability
 

@@ -97,6 +97,7 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 
 from repro.core.sampling import TOP_K_DISABLED, SamplingParams
+from repro.runtime import monitor
 from repro.serve.faults import InjectedAllocFault
 from repro.serve.paged_cache import (
     NULL_PAGE,
@@ -177,7 +178,7 @@ class Request:
     on_token: Optional[Callable] = None
     streamed: int = 0  # tokens of `out` already delivered via on_token
     # -- latency clock (host wall time, time.monotonic seconds) --
-    t_enqueue: float = 0.0  # Scheduler.add
+    t_enqueue: float = 0.0  # its arrival iteration came due
     t_admit: float = 0.0  # first admission to a batch row
     t_first: float = 0.0  # first committed output token
     t_finish: float = 0.0  # terminal outcome recorded
@@ -452,8 +453,6 @@ class Scheduler:
                 f"{req.max_new_tokens} new tokens needs {need} pages, page "
                 f"table holds {self.max_pages_per_req} (page_size {ps})"
             )
-        if req.t_enqueue == 0.0:
-            req.t_enqueue = time.monotonic()
         self.pending.append(req)
 
     def cancel(self, rid: int) -> bool:
@@ -561,6 +560,11 @@ class Scheduler:
                     f"restored allocator"
                 )
             self.slots[slot] = req
+        # monotonic stamps are process-local: requests that had already
+        # arrived restart their latency clock here
+        now = time.monotonic()
+        for req in self.queue + [r for r in self.slots if r is not None]:
+            req.t_enqueue = now
         return [by_rid[rid] for rid in sorted(by_rid)]
 
     # ------------------------------------------------- abort / preempt paths
@@ -640,6 +644,8 @@ class Scheduler:
         for req in list(self.pending):
             if req.arrival > it:
                 continue
+            if req.t_enqueue == 0.0:  # arrived: its queue time starts
+                req.t_enqueue = time.monotonic()
             if self.max_queue is not None and len(self.queue) >= self.max_queue:
                 if self.backpressure == "reject":
                     self._abort(req, FINISH_REJECTED_CAPACITY)
@@ -773,6 +779,12 @@ class Scheduler:
             pick.admitted_at = self.iteration
             if pick.t_admit == 0.0:
                 pick.t_admit = time.monotonic()
+                # the wait RequestResult.queue_time reports, on the trace
+                with monitor.Span(
+                    "serve.admit", rid=pick.rid,
+                    queue_s=pick.t_admit - pick.t_enqueue,
+                ):
+                    pass
             self.slots[slot] = pick
             self._table_stale[slot] = True
         if all(s is None for s in self.slots) and self.queue:
