@@ -216,7 +216,7 @@ def bench_paged_attn(smoke: bool = False):
     ratios the fusion buys.
 
     µs rows time the **jnp forms** of both paths (the Pallas kernel
-    targets TPU; ``ref.paged_attn_ref`` mirrors its online-softmax page
+    targets TPU; ``ref.paged_attn_ref`` mirrors its online-softmax block
     tiling and is the timeable CPU proxy, exactly like the DBB rows).
     REPRO_AUTOTUNE=1 additionally times the two implementations against
     each other and caches the winner under the autotune ``paged_attn``

@@ -239,82 +239,87 @@ def paged_attn_ref(
     out_dtype=None,
 ):
     """jnp oracle for :func:`repro.kernels.paged_attn.paged_attn_fused`,
-    mirroring the kernel's online-softmax **page tiling**: a ``fori_loop``
-    streams one page per step (gathered by id across the batch), applies
-    the same position-derived masking, dequantizes int8 pages in the load
-    (per-token scale column), and carries the flash-style ``(acc, m, l)``
-    statistics — the ``[B, P*PS, D]`` window is never materialized.  This
-    is also the shardable/timeable jnp hot path the CPU benchmarks use
-    (``kernel_bench.bench_paged_attn``), exactly like the other oracles
-    in this module.
+    mirroring the kernel's online-softmax **block tiling**: a
+    ``fori_loop`` streams one block of ``pages_per_block`` pages per step
+    (gathered by id across the batch), applies the same position-derived
+    masking, dequantizes int8 pages in the load (per-token scale, the
+    product rounded to the compute dtype), forms the PV product from the
+    probabilities split in two (``paged_attn._pv``) and carries the
+    flash-style ``(acc, m, l)`` statistics, updating a row only while
+    the block is one of its live blocks (``paged_attn.live_pages``) —
+    the ``[B, P*PS, D]`` window is never materialized.  This is also the shardable/timeable jnp hot
+    path the CPU benchmarks use (``kernel_bench.bench_paged_attn``),
+    exactly like the other oracles in this module.
     """
     import math
+
+    from repro.kernels.paged_attn import live_pages, pages_per_block
 
     b, s, h, dk = q.shape
     g = h // kv_heads
     sg = s * g
-    n_pages, ps = pos_tbl.shape
+    ps = pos_tbl.shape[1]
     p_cnt = page_tables.shape[1]
     latent = latent_dv is not None
     dv = latent_dv if latent else v_pages.shape[-1] // kv_heads
     out_dtype = out_dtype or q.dtype
     scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(dk)
     neg_inf = -1e30  # models/attention.NEG_INF (finite: NaN-free rescale)
+    ppb = pages_per_block(ps, p_cnt)
+    n_blk = -(-p_cnt // ppb)
+    t = ppb * ps
+    tables = jnp.pad(page_tables, ((0, 0), (0, n_blk * ppb - p_cnt)))
+    live_blk = -(-live_pages(q_pos, ps, p_cnt) // ppb)  # [B]
 
     cdtype = q.dtype
     q_r = q.reshape(b, s, kv_heads, g, dk).transpose(0, 2, 1, 3, 4)
     q_r = q_r.reshape(b, kv_heads, sg, dk)
-    k_r = k_pages.reshape(n_pages, ps, kv_heads, dk)
-    v_r = None if latent else v_pages.reshape(n_pages, ps, kv_heads, dv)
 
-    def body(p, carry):
+    def tiles(pages, plane, pid, heads, width):  # -> [B, T, KV, width]
+        x = pages[pid].reshape(b, t, heads, width).astype(jnp.float32)
+        if plane is not None:
+            x = x * plane[pid].reshape(b, t, 1, 1)
+        return x.astype(cdtype)
+
+    def body(j, carry):
         acc, m, l = carry
-        pid = page_tables[:, p]  # [B]
-        k_p = k_r[pid]  # [B, PS, KV, Dk]
-        if k_scale is not None:
-            k_p = (
-                k_p.astype(jnp.float32) * k_scale[pid][:, :, None, None]
-            ).astype(cdtype)
-        logits = (
-            jnp.einsum(
-                "bkxd,bpkd->bkxp", q_r, k_p,
-                preferred_element_type=jnp.float32,
-            )
-            * scale
-        )  # [B, KV, SG, PS]
-        kpos = pos_tbl[pid]  # [B, PS]
+        pid = jax.lax.dynamic_slice_in_dim(tables, j * ppb, ppb, axis=1)
+        k_p = tiles(k_pages, k_scale, pid, kv_heads, dk)
+        logits = jnp.einsum(
+            "bkxd,btkd->bkxt", q_r, k_p, preferred_element_type=jnp.float32
+        )  # [B, KV, SG, T]
+        kpos = pos_tbl[pid].reshape(b, t)
         valid = (kpos[:, None, :] >= 0) & (kpos[:, None, :] <= q_pos[:, :, None])
         if window is not None:
             valid &= kpos[:, None, :] > (q_pos[:, :, None] - window)
-        bias = jnp.where(valid, 0.0, neg_inf).astype(jnp.float32)  # [B, S, PS]
-        logits = logits.reshape(b, kv_heads, s, g, ps) + bias[:, None, :, None, :]
-        logits = logits.reshape(b, kv_heads, sg, ps)
-        m_cur = jnp.max(logits, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m, m_cur)
+        bias = jnp.where(valid, 0.0, neg_inf).astype(jnp.float32)  # [B, S, T]
+        bias = jnp.repeat(bias, g, axis=1)[:, None]  # rows s*G + g'
+        logits = logits * scale + bias
+        m_new = jnp.maximum(m, jnp.max(logits, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         probs = jnp.exp(logits - m_new)
-        if latent:
-            v_p = k_p[..., :dv]  # MLA: v is the latent prefix of k
-        else:
-            v_p = v_r[pid]
-            if v_scale is not None:
-                v_p = (
-                    v_p.astype(jnp.float32) * v_scale[pid][:, :, None, None]
-                ).astype(cdtype)
-        pv = jnp.einsum(
-            "bkxp,bpkv->bkxv", probs.astype(v_p.dtype), v_p,
-            preferred_element_type=jnp.float32,
-        )
+        v_p = k_p[..., :dv] if latent else tiles(v_pages, v_scale, pid, kv_heads, dv)
+        hi = probs.astype(v_p.dtype)  # the kernel's _pv: probs in hi + lo
+
+        def pv_of(p):
+            return jnp.einsum(
+                "bkxt,btkv->bkxv", p, v_p, preferred_element_type=jnp.float32
+            )
+
+        pv = pv_of(hi)
+        if v_p.dtype != jnp.float32:
+            pv = pv + pv_of((probs - hi.astype(jnp.float32)).astype(v_p.dtype))
+        live = (j < live_blk)[:, None, None, None]
         return (
-            acc * alpha + pv,
-            m_new,
-            alpha * l + jnp.sum(probs, axis=-1, keepdims=True),
+            jnp.where(live, acc * alpha + pv, acc),
+            jnp.where(live, m_new, m),
+            jnp.where(live, alpha * l + jnp.sum(probs, axis=-1, keepdims=True), l),
         )
 
     acc = jnp.zeros((b, kv_heads, sg, dv), jnp.float32)
     m = jnp.full((b, kv_heads, sg, 1), neg_inf, jnp.float32)
     l = jnp.zeros((b, kv_heads, sg, 1), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(0, p_cnt, body, (acc, m, l))
+    acc, m, l = jax.lax.fori_loop(0, n_blk, body, (acc, m, l))
     out = acc / jnp.maximum(l, 1e-30)
     out = out.reshape(b, kv_heads, s, g, dv).transpose(0, 2, 1, 3, 4)
     return out.reshape(b, s, h, dv).astype(out_dtype)

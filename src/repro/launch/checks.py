@@ -34,11 +34,11 @@ Row = Tuple[str, bool, str]
 
 # f32 unit roundoff: the accumulation error bound of the float matmuls
 F32_U = 2.0 ** -24
-# fused vs gather paged attention, bf16 K/V and queries: both paths
-# multiply bf16 probabilities into V, but the fused kernel rounds
-# exp(logit - running max) per page while the gather path rounds the
-# normalized softmax, and both round the output to bf16 — a few bf16
-# ulps (2^-8 relative) on outputs of unit scale.  The repo's bf16
+# fused vs gather paged attention, bf16 K/V and queries: the gather
+# path rounds the normalized softmax to bf16 before the PV product,
+# the fused kernel carries exp(logit - running max) to ~2^-17 (two
+# bf16 parts), and both round the output to bf16 — a few bf16 ulps
+# (2^-8 relative) on outputs of unit scale.  The repo's bf16
 # boundary test (tests/test_paged_attn.py) uses the same bound.
 PAGED_ATTN_TOL = 3e-2
 

@@ -56,6 +56,7 @@ from repro.core.sampling import (
     sample_tokens,
     validate_sampling,
 )
+from repro.kernels import paged_attn as paged_attn_k
 from repro.models import attention, common, encdec, lm
 from repro.runtime import monitor
 from repro.serve import faults, paged_cache
@@ -1403,12 +1404,13 @@ class Engine:
         dispatch and its scheduler commit (device KV advanced, host
         bookkeeping not — the torn state snapshots must never see).
         Each iteration runs in a ``serve.iteration`` span (arguments
-        ``it``, ``kind``, ``rows``, ``fed``) whose children name what the
-        host does: ``serve.snapshot``, ``serve.plan``, ``serve.dispatch``,
-        ``serve.sample``, ``serve.wait`` (the blocking read of the step's
-        outputs) and ``serve.commit`` (docs/serving.md "Tracing").  The
-        span's wall time feeds the hang watchdog and the ``health()``
-        percentiles."""
+        ``it``, ``kind``, ``rows``, ``fed``, and ``walked_pages`` where
+        the fused paged-attention kernel serves the step) whose children
+        name what the host does: ``serve.snapshot``, ``serve.plan``,
+        ``serve.dispatch``, ``serve.sample``, ``serve.wait`` (the
+        blocking read of the step's outputs) and ``serve.commit``
+        (docs/serving.md "Tracing").  The span's wall time feeds the hang
+        watchdog and the ``health()`` percentiles."""
         scfg = self.scfg
         cont = self._ensure_cont()
         inj = self._injector
@@ -1453,18 +1455,34 @@ class Engine:
             cont["cache"] = cache
             self._merge_health(sched.stats())
 
+    def _walked_pages(self, plan) -> int:
+        """Pages the fused paged-attention kernel walks in one layer for
+        ``plan`` (``paged_attn.walked_pages``): over a mixed step's
+        positions, or over every fused token of a decode run, whose
+        active rows advance one position a token."""
+        pos = np.asarray(plan.positions)
+        if isinstance(plan, DecodeRun):
+            ahead = pos[:, None] + np.arange(plan.n_steps)
+            pos = np.where(pos[:, None] >= 0, ahead, -1).reshape(-1, 1)
+        return paged_attn_k.walked_pages(
+            pos, self.scfg.page_size, plan.page_tables.shape[1]
+        )
+
     def _dispatch(self, sched, plan, cache, inj, it_span):
         """Run one plan on the device and commit it: the body of a
         ``serve.iteration``.  Returns the updated cache."""
         span = monitor.Span
         tracing = monitor.tracing()
         rows = sum(r is not None for r in plan.rows) if tracing else 0
+        walk = {}
+        if tracing and self._spec is None and self.paged_attn_impl() == "fused":
+            walk["walked_pages"] = self._walked_pages(plan)
         if isinstance(plan, DecodeRun):
             self.decode_run_calls += 1
             if tracing:
                 it_span.annotate(
                     kind="decode_run" if self._spec is None else "spec",
-                    rows=rows, fed=rows * plan.n_steps,
+                    rows=rows, fed=rows * plan.n_steps, **walk,
                 )
             if self._spec is not None:
                 with span("serve.dispatch"):
@@ -1508,7 +1526,7 @@ class Engine:
         fed = sum(plan.n_new)
         self.fed_positions += fed
         if tracing:
-            it_span.annotate(kind="mixed", rows=rows, fed=fed)
+            it_span.annotate(kind="mixed", rows=rows, fed=fed, **walk)
         with span("serve.dispatch"):
             args = (
                 self.params, cache,

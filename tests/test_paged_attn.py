@@ -7,11 +7,11 @@ page layouts: null-page padding, recycled-then-scrubbed pages holding
 stale garbage, and mixed per-request positions — across
 {GQA, MLA} × {f32, int8} KV wires, plus sliding-window masking and the
 bf16 compute-dtype boundary.  The jnp oracle (``ref.paged_attn_ref``)
-mirrors the kernel's online-softmax page tiling and is held to the same
+mirrors the kernel's online-softmax block tiling and is held to the same
 bound.  CI runs this file as a dedicated interpret-mode step so a
 TPU-only regression cannot hide behind the gather fallback.
 
-Tolerances: the fused path regroups the softmax reductions per page
+Tolerances: the fused path regroups the softmax reductions per block
 (flash-style rescaling), so float parity is fp-rounding-bounded
 (~1e-6), not bit-exact — token-level serving parity is asserted in
 ``tests/test_serve.py``.  Comparisons cover valid query rows only:
@@ -29,7 +29,7 @@ import pytest
 from _hypo import given, settings, st  # hypothesis-or-skip shim
 
 from repro.core import quant
-from repro.kernels import autotune, ref
+from repro.kernels import autotune, paged_attn, ref
 from repro.kernels.paged_attn import paged_attn_fused
 from repro.models import attention
 
@@ -201,24 +201,28 @@ def test_sliding_window_masking():
 
 
 def test_recycled_page_scrub_invariant():
-    """A page whose positions were scrubbed to -1 (recycled) contributes
-    exactly zero even when it streams FIRST (its garbage accumulates
-    into the online stats, then the first valid page's rescale flushes
-    it) — the null-page/scrub invariant the gather path documents."""
-    kvh, dh = 1, 8
+    """Pages whose positions were scrubbed to -1 (recycled) contribute
+    exactly zero even when a whole block of them streams FIRST (their
+    garbage accumulates into the online stats, then the first valid
+    block's rescale flushes it) — the null-page/scrub invariant the
+    gather path documents.  The request holds positions 0..139 on
+    16-token pages; the first eight (one block) are scrubbed and refilled
+    with huge stale values, and the query at 139 still reads its
+    frontier page."""
+    kvh, dh, ps = 1, 8, 16
     cache, pos_tbl, tables = make_paged_state(
-        6, n_tokens=(5,), n_pages=6, ps=4, kvd=kvh * dh, garbage_scale=100.0
+        6, n_tokens=(140,), n_pages=12, ps=ps, kvd=kvh * dh
     )
-    # prepend a "recycled" page: real id, huge stale values, pos all -1
-    stale = 5 if int(tables[0, 0]) != 5 else 4
-    tables_stale = jnp.asarray([[stale, *np.asarray(tables[0, :-1])]], jnp.int32)
-    pos_tbl = pos_tbl.at[stale].set(-1)
+    stale = tables[0, :8]
     rng = np.random.default_rng(7)
+    for name in ("k", "v"):
+        cache[name] = cache[name].at[stale].set(
+            _rand(rng, (8, ps, kvh * dh), 100.0))
+    pos_tbl = pos_tbl.at[stale].set(-1)
     q = _rand(rng, (1, 1, 2, dh))
-    q_pos = jnp.asarray([[4]], jnp.int32)
-    # reference: the same request WITHOUT the stale page in its table
+    q_pos = jnp.asarray([[139]], jnp.int32)
     out_ref = _gather_mha(q, cache, pos_tbl, tables, q_pos, kvh, dh)
-    out_k = _fused(q, cache, pos_tbl, tables_stale, q_pos, kvh)
+    out_k = _fused(q, cache, pos_tbl, tables, q_pos, kvh)
     np.testing.assert_allclose(
         np.asarray(out_k), np.asarray(out_ref), atol=1e-5, rtol=1e-5
     )
@@ -278,6 +282,132 @@ def test_oracle_mirrors_kernel(int8):
     out_g = _gather_mha(q, cache, pos_tbl, tables, q_pos, kvh, dh)
     np.testing.assert_allclose(
         np.asarray(out_o), np.asarray(out_g), atol=1e-5, rtol=1e-5
+    )
+
+
+# Tables wider than the live context, on 16-token pages (8 pages, 128
+# keys, a block): a 150-token request whose query ends mid-page in the
+# second block, a 37-token one ending mid-page in the first, a row whose
+# queries are all padding, and a frontier on a block's last key.  The
+# table holds 11 pages (the widest request's 10 and a null column), so
+# P is no multiple of the block.  ``kvd`` 128 takes the kernel's by-hand
+# page copies, 32 its page BlockSpecs.
+WIDE = dict(n_tokens=(150, 37, 20, 128), ps=16)
+WIDE_POS = {1: [[149], [36], [-1], [127]],
+            3: [[147, 148, 149], [34, 35, 36], [-1, -1, -1], [125, 126, 127]]}
+FETCH = {"by_hand": (2, 64), "spec": (2, 16)}  # (kv heads, head dim)
+
+
+def _wide_case(seed, fetch, s, int8):
+    kvh, dh = FETCH[fetch]
+    cache, pos_tbl, tables = make_paged_state(
+        seed, n_pages=40, kvd=kvh * dh, int8=int8, **WIDE
+    )
+    rng = np.random.default_rng(seed + 1)
+    q = _rand(rng, (4, s, 8, dh))
+    return cache, pos_tbl, tables, q, jnp.asarray(WIDE_POS[s], jnp.int32)
+
+
+@pytest.mark.parametrize("fetch", FETCH)
+@pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
+@pytest.mark.parametrize("s", [1, 3], ids=["decode", "chunk"])
+def test_live_walk_matches_gather(fetch, int8, s):
+    """The walk stops at each row's live pages: over a table wider than
+    the live context the kernel equals the gather path (which reads the
+    whole table) on every valid query row, and a row with no valid query
+    (no live page) comes out finite."""
+    kvh, dh = FETCH[fetch]
+    cache, pos_tbl, tables, q, q_pos = _wide_case(20, fetch, s, int8)
+    assert tables.shape[1] == 11
+    out_ref = _gather_mha(q, cache, pos_tbl, tables, q_pos, kvh, dh)
+    out_k = _fused(q, cache, pos_tbl, tables, q_pos, kvh)
+    valid = np.asarray(q_pos) >= 0
+    np.testing.assert_allclose(
+        np.asarray(out_k)[valid], np.asarray(out_ref)[valid],
+        atol=1e-5, rtol=1e-5,
+    )
+    assert np.all(np.isfinite(np.asarray(out_k)))
+
+
+@pytest.mark.parametrize("fetch", FETCH)
+@pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
+@pytest.mark.parametrize("s", [1, 3], ids=["decode", "chunk"])
+def test_oracle_mirrors_kernel_live_blocks(fetch, int8, s):
+    """ref.paged_attn_ref walks the same live blocks as the kernel: on
+    the wide tables it matches the kernel on every row, padding rows
+    included."""
+    kvh, _ = FETCH[fetch]
+    cache, pos_tbl, tables, q, q_pos = _wide_case(22, fetch, s, int8)
+    out_k = _fused(q, cache, pos_tbl, tables, q_pos, kvh)
+    out_o = ref.paged_attn_ref(
+        q, cache["k"], cache["v"], pos_tbl, tables, q_pos, kv_heads=kvh,
+        k_scale=cache.get("k_scale"), v_scale=cache.get("v_scale"),
+    )
+    np.testing.assert_allclose(
+        np.asarray(out_o), np.asarray(out_k), atol=1e-6, rtol=1e-6
+    )
+
+
+@pytest.mark.parametrize("lora", [24, 120], ids=["spec", "by_hand"])
+@pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
+@pytest.mark.parametrize("s", [1, 3], ids=["decode", "chunk"])
+def test_mla_live_walk_matches_absorbed(lora, int8, s):
+    """MLA's latent mode over tables wider than the live context: the
+    kernel equals the absorbed math over the whole gathered latent on
+    every valid query row (latent width 32 takes the page BlockSpecs,
+    128 the by-hand copies)."""
+    rope_d, h = 8, 4
+    cache, pos_tbl, tables = make_paged_state(
+        24, n_pages=40, kvd=lora + rope_d, **WIDE
+    )
+    if int8:  # MLA quantizes only the latent k plane
+        qk, sk = quant.quantize_rows(cache["k"])
+        cache = {"k": qk, "k_scale": sk, "v": cache["v"]}
+    rng = np.random.default_rng(25)
+    q_cat = _rand(rng, (4, s, h, lora + rope_d))
+    q_pos = jnp.asarray(WIDE_POS[s], jnp.int32)
+    scale = 1.0 / math.sqrt(lora + rope_d)
+    lat, _, pos_win = attention.paged_read(
+        cache, pos_tbl, tables, dtype=jnp.float32
+    )
+    logits = jnp.einsum("bshd,btd->bhst", q_cat, lat,
+                        preferred_element_type=jnp.float32) * scale
+    bias = attention._mask_bias(q_pos, pos_win, None)[:, None, :, :]
+    probs = jax.nn.softmax(logits + bias, axis=-1)
+    ctx_ref = jnp.einsum("bhst,btl->bshl", probs, lat[..., :lora],
+                         preferred_element_type=jnp.float32)
+    ctx_k = paged_attn_fused(
+        q_cat, cache["k"], None, pos_tbl, tables, q_pos,
+        kv_heads=1, softmax_scale=scale, latent_dv=lora,
+        k_scale=cache.get("k_scale"), interpret=True,
+    )
+    valid = np.asarray(q_pos) >= 0
+    np.testing.assert_allclose(
+        np.asarray(ctx_k)[valid], np.asarray(ctx_ref)[valid],
+        atol=1e-5, rtol=1e-5,
+    )
+    assert np.all(np.isfinite(np.asarray(ctx_k)))
+
+
+@pytest.mark.parametrize("ps,s", [(16, 1), (16, 3), (4, 2), (256, 1)])
+def test_walked_pages_counts_live_blocks(ps, s):
+    """The host's count of walked pages is each row's live pages (the
+    page of its largest query position and those before it, none for an
+    all-padding row, at most the table) rounded up to whole blocks."""
+    rng = np.random.default_rng(ps + s)
+    p_cnt = 11
+    q_pos = rng.integers(-1, ps * p_cnt + 5, (6, s)).astype(np.int32)
+    q_pos[0] = -1
+    ppb = paged_attn.pages_per_block(ps, p_cnt)
+    want = 0
+    for row in q_pos:
+        top = row.max()
+        live = 0 if top < 0 else min(top // ps + 1, p_cnt)
+        want += -(-live // ppb) * ppb
+    assert paged_attn.walked_pages(q_pos, ps, p_cnt) == want
+    np.testing.assert_array_equal(
+        np.asarray(paged_attn.live_pages(jnp.asarray(q_pos), ps, p_cnt)),
+        paged_attn.live_pages(q_pos, ps, p_cnt),
     )
 
 
@@ -357,6 +487,37 @@ def test_fused_matches_gather_property(seed, lens, ps, int8):
     q_pos = jnp.asarray(
         [[int(rng.integers(0, t))] for t in lens], jnp.int32
     )
+    out_ref = _gather_mha(q, cache, pos_tbl, tables, q_pos, kvh, dh)
+    out_k = _fused(q, cache, pos_tbl, tables, q_pos, kvh)
+    np.testing.assert_allclose(
+        np.asarray(out_k), np.asarray(out_ref), atol=1e-5, rtol=1e-5
+    )
+
+
+@settings(max_examples=15, deadline=None)
+@given(
+    seed=st.integers(0, 2**16),
+    lens=st.lists(st.integers(1, 200), min_size=1, max_size=3),
+    ps=st.sampled_from([4, 8, 16]),
+    extra=st.integers(0, 12),
+    int8=st.booleans(),
+    wide=st.booleans(),
+)
+def test_live_walk_property(seed, lens, ps, extra, int8, wide):
+    """Property: over random live lengths in tables up to ``extra`` pages
+    wider than the longest request (so rows end mid-page and mid-block,
+    and ``P`` falls anywhere against the block), with queries at random
+    valid positions, the fused kernel equals the gather+mha path."""
+    kvh, dh = (2, 64) if wide else (2, 8)
+    n_pages = sum(-(-t // ps) for t in lens) + 2
+    cache, pos_tbl, tables = make_paged_state(
+        seed, n_tokens=tuple(lens), n_pages=n_pages, ps=ps, kvd=kvh * dh,
+        int8=int8,
+    )
+    tables = jnp.pad(tables, ((0, 0), (0, extra)))
+    rng = np.random.default_rng(seed + 1)
+    q = _rand(rng, (len(lens), 1, 4, dh))
+    q_pos = jnp.asarray([[int(rng.integers(0, t))] for t in lens], jnp.int32)
     out_ref = _gather_mha(q, cache, pos_tbl, tables, q_pos, kvh, dh)
     out_k = _fused(q, cache, pos_tbl, tables, q_pos, kvh)
     np.testing.assert_allclose(

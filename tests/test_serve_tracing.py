@@ -7,11 +7,13 @@ import dataclasses
 import gc
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from bench import spans as trace_spans
 from repro import configs
+from repro.kernels import paged_attn
 from repro.models import lm
 from repro.serve import scheduler as sched_mod
 from repro.serve.engine import Engine, ServeConfig
@@ -195,3 +197,72 @@ def test_trace_keeps_the_scopes_of_both_programs(int8_engine):
     for prog in ("jit_paged_step", "jit_decode_run"):
         (scopes,) = [m for n, m in by_program.items() if n.startswith(prog + "(")]
         assert {"dbb_linear", "dap_pack"} <= set(scopes.values()), prog
+
+
+def _kernel_walk(q_pos, ps, p_cnt):
+    """Pages the fused kernel walks a layer for ``[B, S]`` positions, as
+    its wrapper counts them: live pages per row (on the device), rounded
+    up to whole blocks."""
+    ppb = paged_attn.pages_per_block(ps, p_cnt)
+    live = np.asarray(paged_attn.live_pages(jnp.asarray(q_pos, jnp.int32), ps, p_cnt))
+    return int(sum(-(-n // ppb) * ppb for n in live))
+
+
+@pytest.fixture(scope="module")
+def fused_traced(tmp_path_factory):
+    """A traced call of an engine on the fused kernel (the interpreter
+    here), with a copy of every plan it ran."""
+    eng = _engine(small_cfg(), paged_attn="fused", max_seq=320, page_size=8,
+                  max_pages=64)
+    prompts = _prompts((45, 6, 21))
+    eng.serve_requests(prompts, 5)  # compile outside the trace
+    plans = []
+    real_plan = Scheduler.plan
+
+    def plan(self):
+        p = real_plan(self)
+        if p is not None:
+            plans.append((np.array(p.positions), np.array(p.page_tables),
+                           getattr(p, "n_steps", None)))
+        return p
+
+    out = tmp_path_factory.mktemp("trace")
+    Scheduler.plan = plan
+    try:
+        with jax.profiler.trace(str(out)):
+            eng.serve_requests(prompts, 5)
+    finally:
+        Scheduler.plan = real_plan
+    its = [s for s in trace_spans.load(out).spans if s.name == "serve.iteration"]
+    return eng, its, plans
+
+
+def test_walked_pages_match_the_kernel(fused_traced):
+    """``walked_pages`` on a mixed plan is the kernel's block-rounded live
+    pages for its positions, and on a decode run the sum over its fused
+    tokens, each a step further along for every active row."""
+    eng, its, plans = fused_traced
+    ps = eng.scfg.page_size
+    assert len(its) == len(plans)
+    kinds = set()
+    for it, (pos, tables, n_steps) in zip(its, plans):
+        p_cnt = tables.shape[1]
+        if n_steps is None:
+            want = _kernel_walk(pos, ps, p_cnt)
+        else:
+            want = sum(_kernel_walk(np.where(pos >= 0, pos + i, -1)[:, None],
+                                    ps, p_cnt) for i in range(n_steps))
+        kinds.add(it.args["kind"])
+        assert it.args["walked_pages"] == want, it.args
+    assert kinds == {"mixed", "decode_run"}
+    # tables of 40 pages, blocks of 16, no request past 50 positions: a
+    # live row walks one block, a fraction of its table
+    assert paged_attn.pages_per_block(ps, 40) == 16
+    calls = sum(n or 1 for _, _, n in plans)
+    assert 0 < sum(it.args["walked_pages"] for it in its) <= 3 * 16 * calls
+
+
+def test_gather_path_walks_no_pages(traced):
+    """On the gather path no kernel walks pages: no span carries them."""
+    its = [s for s in traced[0] if s.name == "serve.iteration"]
+    assert its and not any("walked_pages" in s.args for s in its)

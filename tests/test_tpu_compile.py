@@ -56,10 +56,21 @@ def _compile(one_chip, fn, *shapes):
     assert "tpu_custom_call" in text  # the Mosaic kernel, not a fallback
 
 
-@pytest.mark.parametrize("kv", ["bf16", "int8"])
-@pytest.mark.parametrize("s", [1, 64], ids=["decode", "chunk64"])
-def test_paged_attn_fused_compiles(one_chip, kv, s):
-    b, p_cnt, n_pages = 8, 40, 321
+# (kv wire, S, B, P, pool pages): a small table, and the served shape of
+# the granite-3-8b code-burst cell (16 rows of 256 pages, a 1152-page pool)
+PAGED_CASES = {
+    "decode-bf16": ("bf16", 1, 8, 40, 321),
+    "decode-int8": ("int8", 1, 8, 40, 321),
+    "chunk64-bf16": ("bf16", 64, 8, 40, 321),
+    "chunk64-int8": ("int8", 64, 8, 40, 321),
+    "served-decode-int8": ("int8", 1, 16, 256, 1152),
+    "served-chunk64-int8": ("int8", 64, 16, 256, 1152),
+}
+
+
+@pytest.mark.parametrize("case", PAGED_CASES.values(), ids=PAGED_CASES.keys())
+def test_paged_attn_fused_compiles(one_chip, case):
+    kv, s, b, p_cnt, n_pages = case
     shapes = [
         ((b, s, HEADS, HEAD_DIM), jnp.bfloat16),
         ((n_pages, PAGE, KV_DIM), jnp.int8 if kv == "int8" else jnp.bfloat16),
