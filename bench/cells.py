@@ -1,9 +1,15 @@
-"""Cells by name: a configuration file, a traffic mix and a chip count.
+"""Cells by name: a configuration file, a traffic mix, a chip count and
+the limits that decide ``correct``.
 
 A cell listed in ``BENCHMARK.json`` takes its configuration, mix and
 chips from there.  Any other name ``<config>.<traffic>`` is a cell as
-soon as ``configs/<config>.json`` and ``traffic/<traffic>.json`` exist,
-so a new cell needs new files and no edit.
+soon as ``configs/<config>.json`` and ``traffic/<traffic>.json`` exist.
+A cell's correctness limits are ``checks/<cell>.json`` (``bench/check.py``
+says what they compare); a cell without that file is never correct.
+
+So a new cell needs new files and no edit: ``configs/<config>.json``
+where the configuration is new, ``traffic/<traffic>.json`` where the mix
+is new, ``checks/<cell>.json``, and the cell's entry in ``BENCHMARK.json``.
 """
 
 from __future__ import annotations
@@ -16,6 +22,7 @@ from typing import Optional
 BENCH = pathlib.Path(__file__).resolve().parent
 CONFIG_DIR = BENCH / "configs"
 TRAFFIC_DIR = BENCH / "traffic"
+CHECK_DIR = BENCH / "checks"
 METRIC_DIR = BENCH / "metrics"
 BENCHMARK_JSON = BENCH.parent / "BENCHMARK.json"
 # what bench/run.py can measure end to end; a cell made of files alone
@@ -32,6 +39,7 @@ class Cell:
     chips: int
     per_layer: list  # names of the per-layer metrics this cell reports
     end_to_end: list  # names of the end-to-end metrics it reports
+    limits: dict  # checks/<cell>.json as written; {} without the file
 
 
 def _benchmark() -> Optional[dict]:
@@ -56,6 +64,7 @@ def resolve(
     config_dir: pathlib.Path = CONFIG_DIR,
     traffic_dir: pathlib.Path = TRAFFIC_DIR,
     metric_dir: pathlib.Path = METRIC_DIR,
+    check_dir: pathlib.Path = CHECK_DIR,
 ) -> Cell:
     bench = _benchmark()
     conf = mix = None
@@ -85,4 +94,6 @@ def resolve(
         end_to_end = list(END_TO_END)
     config = json.loads((config_dir / f"{conf}.json").read_text())
     mix_d = json.loads((traffic_dir / f"{mix}.json").read_text())
-    return Cell(name, config, mix_d, mix, chips, per_layer, end_to_end)
+    limits_file = check_dir / f"{name}.json"
+    limits = json.loads(limits_file.read_text()) if limits_file.is_file() else {}
+    return Cell(name, config, mix_d, mix, chips, per_layer, end_to_end, limits)

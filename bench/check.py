@@ -17,11 +17,13 @@ chose the reference's argmax).  Two numbers are compared:
   median: a mean of two or three ranks swings on sound runs as far as
   the control reads.
 
-Their limits, by traffic mix, are the ``check`` block of
-``configs/<config>.json``; a cell whose mix has no limit there is not
-correct.  Each request's median rank, ``mismatch_share`` and the widest
-and the mean logit gap are printed beside them and compared with
-nothing.
+Their limits are the cell's own file, ``checks/<cell>.json``
+(``{"median_rank": n, "worst_request_rank": n, "why": "<the readings
+that set them>"}``), which ``bench/cells.py`` reads into ``Cell.limits``;
+a cell without that file, or with a limit missing or ``null``, is not
+correct.
+Each request's median rank, ``mismatch_share`` and the widest and the
+mean logit gap are printed beside them and compared with nothing.
 
 The control (``--control w4``) is the same reference with 4-bit weights
 in the program's place.  At the same positions it serves the token it
@@ -36,13 +38,13 @@ import numpy as np
 
 CONTROLS = {"w4": (4, 8)}  # (weight bits, activation bits)
 MIN_TOKENS = 5  # served tokens a request needs to count in worst_request_rank
+NUMBERS = ("median_rank", "worst_request_rank")  # what a limit is set on
 
 
 def limits_for(cell) -> dict:
-    """``{number: limit}`` the configuration compares under this cell's
-    mix; a limit no reading has set yet is ``None``, and fails."""
-    return {name: by_mix.get(cell.traffic)
-            for name, by_mix in cell.config["check"].items()}
+    """``{number: limit}`` of ``checks/<cell>.json`` for each compared
+    number; a limit the file does not set is ``None``, and fails."""
+    return {name: cell.limits.get(name) for name in NUMBERS}
 
 
 def readings(logits, tokens, lengths) -> dict:
@@ -88,12 +90,14 @@ def sample(cell, seed, finished):
 
 
 def padded_length(cell) -> int:
-    """The reference's fixed sequence length: the cell's ``max_seq``
-    rounded up, one compiled shape for every seed."""
+    """The reference's fixed sequence length: the mix's longest request
+    (document, prompt and answer at their largest), at most the cell's
+    ``max_seq``, rounded up; one compiled shape for every seed."""
     from bench.reference.model import SEQ_MULTIPLE
+    from bench.traffic import longest_request
 
     serve = {**cell.config["serve"], **cell.mix.get("serve", {})}
-    n = serve["max_seq"]
+    n = min(longest_request(cell.mix), serve["max_seq"])
     return -(-n // SEQ_MULTIPLE) * SEQ_MULTIPLE
 
 
@@ -133,7 +137,7 @@ def failed(cell) -> dict:
 
 def verdict(read: dict, limits: dict) -> dict:
     return {
-        "correct": bool(limits) and all(
+        "correct": all(
             v is not None and read[k] <= v for k, v in limits.items()),
         "check": {k: {"value": read[k], "limit": v} for k, v in limits.items()},
     }

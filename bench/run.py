@@ -2,6 +2,10 @@
 
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
+A cell (``bench/cells.py``) is made of files alone: ``configs/<config>.json``,
+``traffic/<traffic>.json``, its correctness limits ``checks/<cell>.json``
+and its entry in ``BENCHMARK.json``; a new one needs new files and no edit.
+
 One process.  It builds the configuration's packed int8 DBB weights on
 the device from ``--seed`` (``engine.init_serving_params``), the
 ``Engine`` with the configuration's serving fields, warms up the two
@@ -17,7 +21,8 @@ requests the window finished against the plain reference
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` and, last, ``check``
 (each compared number beside its limit, which the last lines of
-standard error repeat).  ``--trace 0`` reports the end-to-end metrics;
+standard error repeat; the limits are the cell's ``checks/<cell>.json``,
+``bench/check.py``).  ``--trace 0`` reports the end-to-end metrics;
 ``--trace 1`` traces the first burst of the window instead, stops after
 it, and reports the per-layer metrics (``bench/metrics/<name>.py``),
 the device's busy and traced seconds and a ``breakdown`` of device
@@ -178,10 +183,11 @@ def counters(engine):
 
 
 def main(argv=None, *, require_tpu=True, config_dir=cells.CONFIG_DIR,
-         traffic_dir=cells.TRAFFIC_DIR) -> int:
+         traffic_dir=cells.TRAFFIC_DIR, check_dir=cells.CHECK_DIR) -> int:
     args = parse_args(argv)
     try:
-        cell = cells.resolve(args.workload, config_dir, traffic_dir)
+        cell = cells.resolve(args.workload, config_dir, traffic_dir,
+                             check_dir=check_dir)
     except KeyError as err:
         return fail(str(err))
     sys.path.insert(0, str(REPO / "src"))

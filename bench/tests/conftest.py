@@ -1,6 +1,7 @@
 """Smoke-sized cells for the harness's CPU tests: the reduced
-same-family configurations of both archs, written as data files into a
-temporary directory, which is how any new cell is made."""
+same-family configurations of both archs, a mix and each cell's limits,
+written as data files into a temporary directory, which is how any new
+cell is made."""
 
 import json
 import pathlib
@@ -34,25 +35,35 @@ SMOKE_MIX = {
 # three seeds two CPU layers of width 128 read a pooled median rank of
 # 0-0.5 and a worst request rank of 0.4-1.3, the 4-bit control 4-16 and
 # 9.8-19.3.
-SMOKE_CHECK = {"median_rank": {"chat": 2}, "worst_request_rank": {"chat": 4}}
+SMOKE_LIMITS = {"median_rank": 2, "worst_request_rank": 4,
+                "why": "smoke size on the CPU: the readings above"}
 
 
 def smoke_config(name: str) -> dict:
     conf = json.loads((BENCH / "configs" / f"{name}.json").read_text())
-    conf.update(name=f"{name}-smoke", smoke=True, check=SMOKE_CHECK)
+    conf.update(name=f"{name}-smoke", smoke=True)
     conf["model"].update(SMOKE_MODELS[name])
     conf["serve"].update(max_batch=4, paged_attn="fused")  # the chip's kernel
     return conf
 
 
+def write_smoke_cell(cdir, tdir, kdir, name: str) -> str:
+    """Write the files of the cell ``<name>-smoke.chat`` and return its name."""
+    for d in (cdir, tdir, kdir):
+        d.mkdir(exist_ok=True)
+    cell = f"{name}-smoke.chat"
+    (cdir / f"{name}-smoke.json").write_text(json.dumps(smoke_config(name)))
+    (tdir / "chat.json").write_text(json.dumps(SMOKE_MIX))
+    (kdir / f"{cell}.json").write_text(json.dumps(SMOKE_LIMITS))
+    return cell
+
+
 @pytest.fixture
 def smoke_dirs(tmp_path):
-    """``(config_dir, traffic_dir)`` holding ``<arch>-smoke`` configs and a
-    ``chat`` mix; nothing in the repository is edited."""
-    cdir, tdir = tmp_path / "configs", tmp_path / "traffic"
-    cdir.mkdir()
-    tdir.mkdir()
+    """``(config_dir, traffic_dir, check_dir)`` holding ``<arch>-smoke``
+    configs, a ``chat`` mix and the limits of each ``<arch>-smoke.chat``
+    cell; nothing in the repository is edited."""
+    dirs = tmp_path / "configs", tmp_path / "traffic", tmp_path / "checks"
     for name in SMOKE_MODELS:
-        (cdir / f"{name}-smoke.json").write_text(json.dumps(smoke_config(name)))
-    (tdir / "chat.json").write_text(json.dumps(SMOKE_MIX))
-    return cdir, tdir
+        write_smoke_cell(*dirs, name)
+    return dirs

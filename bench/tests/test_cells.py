@@ -1,5 +1,5 @@
-"""Cells are found by name from files, and the harness refuses to run
-where there is no chip or no program."""
+"""Cells are found by name from files, their limits with them, and the
+harness refuses to run where there is no chip or no program."""
 
 import json
 import os
@@ -10,7 +10,8 @@ import sys
 
 import pytest
 
-from bench import cells
+from bench import cells, check, run
+from bench.tests.conftest import SMOKE_LIMITS, SMOKE_MODELS, write_smoke_cell
 
 BENCH = pathlib.Path(__file__).resolve().parents[1]
 REPO = BENCH.parent
@@ -26,6 +27,36 @@ def test_benchmark_json_names_existing_files():
         assert (cells.TRAFFIC_DIR / f"{w['traffic']}.json").is_file()
         for name in cell.per_layer:
             assert (cells.METRIC_DIR / f"{name}.py").is_file()
+
+
+def test_every_cell_has_its_limits():
+    bench = json.loads(cells.BENCHMARK_JSON.read_text())
+    for w in bench["workloads"]:
+        limits = check.limits_for(cells.resolve(w["name"]))
+        assert set(limits) == {"median_rank", "worst_request_rank"}, w["name"]
+        for v in limits.values():
+            assert type(v) in (int, float), (w["name"], limits)
+
+
+@pytest.mark.parametrize("name", sorted(SMOKE_MODELS))
+def test_cell_from_new_files_alone(tmp_path, name):
+    """A configuration, a mix and a limits file that nothing in the
+    repository names make a cell the harness judges by those limits."""
+    dirs = tmp_path / "configs", tmp_path / "traffic", tmp_path / "checks"
+    cell = cells.resolve(write_smoke_cell(*dirs, name), dirs[0], dirs[1],
+                         check_dir=dirs[2])
+    assert (cell.config["name"], cell.traffic, cell.chips) == (
+        f"{name}-smoke", "chat", 1)
+    assert check.limits_for(cell) == {
+        k: v for k, v in SMOKE_LIMITS.items() if k != "why"}
+    run.model_config(cell)  # the program takes the file as written
+
+
+def test_reference_length_of_code_burst():
+    """Padding the reference to the mix's longest request leaves
+    code-burst's length where ``max_seq`` put it."""
+    cell = cells.resolve("granite-3-8b.code-burst")
+    assert check.padded_length(cell) == 4096 == cell.config["serve"]["max_seq"]
 
 
 def test_new_traffic_file_makes_a_cell(tmp_path):

@@ -10,19 +10,21 @@ import io
 import json
 
 import jax.numpy as jnp
+import pytest
 
-from bench import run
+from bench import check, run
 
 CELL = "granite-3-8b-smoke.chat"
 
 
 def _run(smoke_dirs, *extra):
-    cdir, tdir = smoke_dirs
+    cdir, tdir, kdir = smoke_dirs
     buf = io.StringIO()
     with contextlib.redirect_stdout(buf):
         rc = run.main(["--workload", CELL, "--seed", str(2**31 + 5),
                        "--seconds", "0.5", "--trace", "0", *extra],
-                      require_tpu=False, config_dir=cdir, traffic_dir=tdir)
+                      require_tpu=False, config_dir=cdir, traffic_dir=tdir,
+                      check_dir=kdir)
     assert rc == 0
     return json.loads(buf.getvalue().strip().splitlines()[-1])
 
@@ -39,6 +41,22 @@ def test_sound_run_passes_and_the_control_fails(smoke_dirs):
     assert control["correct"] is False  # the control's own verdict
     assert control["control"]["name"] == "w4" and control["readings"]["tokens"] > 0
     assert any(item["value"] > item["limit"] for item in control["check"].values())
+
+
+@pytest.mark.parametrize("limits", [
+    None, {"median_rank": None, "worst_request_rank": 4}, {"median_rank": 2}])
+def test_cell_without_limits_is_not_correct(smoke_dirs, limits):
+    """No limits file, or a limit no reading has set or the file leaves
+    out: never correct."""
+    path = smoke_dirs[2] / f"{CELL}.json"
+    if limits is None:
+        path.unlink()
+    else:
+        path.write_text(json.dumps(limits))
+    out = _run(smoke_dirs)
+    assert out["correct"] is False
+    assert out["failed"] == 0 and out["readings"]["tokens"] > 0
+    assert list(out)[-1] == "check" and set(out["check"]) == set(check.NUMBERS)
 
 
 def _alter(monkeypatch, rows):

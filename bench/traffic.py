@@ -63,6 +63,12 @@ def load_mix(name: str, directory: pathlib.Path = TRAFFIC_DIR) -> dict:
     return json.loads(path.read_text())
 
 
+def longest_request(mix: dict) -> int:
+    """Longest prompt plus output any request of ``mix`` can have."""
+    doc = mix.get("document", {}).get("tokens", 0)
+    return doc + mix["prompt"]["max"] + mix["output"]["max"]
+
+
 def stratified_lengths(spec: dict, n: int) -> List[int]:
     """The ``n`` lognormal quantiles at ``(i + 0.5) / n``, clipped to
     ``[min, max]`` and rounded, in ascending order."""
@@ -113,8 +119,3 @@ class Generator:
                 sample_seed=int(self.rng.integers(0, 2**31 - 1)),
             ))
         return out
-
-    def max_total(self) -> int:
-        """Longest prompt plus output any request of this mix can have."""
-        doc = len(self.document) if self.document is not None else 0
-        return doc + self.mix["prompt"]["max"] + self.mix["output"]["max"]
